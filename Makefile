@@ -19,7 +19,7 @@ help:
 	@echo "  vectors [VECTOR_OUT=dir] |"
 	@echo "  kzg_setups | bench (real TPU) | bench-smoke (tiny CPU shapes,"
 	@echo "  asserts the bench JSON contract) | bench-report (benchwatch"
-	@echo "  trend/threshold dashboard over the checked-in rounds +"
+	@echo "  trend/threshold dashboard over the round files in --repo +"
 	@echo "  out/bench_history.jsonl; exits nonzero on regression) |"
 	@echo "  serve (sustained-load verification service, real TPU;"
 	@echo "  CST_TRACE_REQUESTS=1 adds per-request tail-latency"
@@ -83,7 +83,9 @@ bench:
 bench-smoke:
 	$(CPU_ENV) $(PYTHON) bench_smoke.py
 
-# benchwatch: ingest BENCH_r*/MULTICHIP_r* rounds, baselines, and any
+# benchwatch: ingest the driver's BENCH_r*/MULTICHIP_r* round files found
+# in the checkout (only MULTICHIP_r04/r05 are checked in), the oracle
+# baselines, and any
 # telemetry snapshot into out/bench_history.jsonl, render the markdown
 # trend + ROADMAP-threshold dashboard (out/bench_report.md), and exit
 # nonzero on a round-over-round regression (CI gates on this; stdlib

@@ -157,8 +157,8 @@ def _check_costmodel(tel, where: str, expect_substrings=()) -> dict:
 
 def main():
     out = _run(["bench.py", "--worker", "epoch"],
-               {"CST_BENCH_N": "1024", "CST_NO_COMPILE_CACHE": "1",
-                "CST_TELEMETRY": "1", "CST_COSTMODEL": "1"},
+               {"CST_BENCH_N": "1024", "CST_TELEMETRY": "1",
+                "CST_COSTMODEL": "1"},
                timeout=900)
     last = out[-1]
     assert isinstance(last.get("seconds"), (int, float)) \
@@ -992,7 +992,7 @@ def shard_main():
     shard_t0 = time.time()
     out = _run(["bench.py", "--worker", "scaling"],
                {"CST_SHARD_RUNGS": "4096,8192", "CST_SHARD_ITERS": "2",
-                "CST_NO_COMPILE_CACHE": "1", "CST_TELEMETRY": "1",
+                "CST_TELEMETRY": "1",
                 "XLA_FLAGS": os.environ.get("XLA_FLAGS")
                 or "--xla_force_host_platform_device_count=8"},
                timeout=900)
@@ -1108,7 +1108,7 @@ def das_main():
                {"CST_DAS_MATRIX": "128x8", "CST_DAS_ORACLE_CELLS": "8",
                 "CST_DAS_PRODUCE_ITERS": "1", "CST_DAS_DU_MSMS": "1",
                 "CST_DAS_RECOVER_ORACLE_COSETS": "1",
-                "CST_NO_COMPILE_CACHE": "1", "CST_TELEMETRY": "1"},
+                "CST_TELEMETRY": "1"},
                timeout=3600)
     last = out[-1]
     rec = last.get("das_cell_proof_batch_128x8_verify_wall")
@@ -1251,7 +1251,7 @@ def forkchoice_main():
     out = _run(["bench.py", "--worker", "forkchoice"],
                {"CST_FC_MATRIX": "64x1024",
                 "CST_FC_ORACLE_VALIDATORS": "256",
-                "CST_NO_COMPILE_CACHE": "1", "CST_TELEMETRY": "1"},
+                "CST_TELEMETRY": "1"},
                timeout=900)
     last = out[-1]
     rec = last.get("forkchoice_lmd_ghost_64x1024_head_wall")

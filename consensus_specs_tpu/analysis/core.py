@@ -507,15 +507,14 @@ class ModuleModel:
                     break
 
         # functions handed to jit/shard_map by reference: jax.jit(run),
-        # shard_map_compat(local, ...)
+        # jax.shard_map(local, ...)
         referenced: set[str] = set()
         for node in ast.walk(self.tree):
             if not isinstance(node, ast.Call) or not node.args:
                 continue
             fd = _dotted(node.func)
             is_wrap = (fd in ("jit", "jax.jit")
-                       or (fd or "").split(".")[-1] in (
-                           "shard_map", "shard_map_compat"))
+                       or (fd or "").split(".")[-1] == "shard_map")
             if is_wrap and isinstance(node.args[0], ast.Name):
                 referenced.add(node.args[0].id)
 

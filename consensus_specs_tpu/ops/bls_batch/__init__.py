@@ -512,10 +512,9 @@ def _msm_sharded_kernel(n_devices: int, per_shard: int, c: int,
             lambda co: jax.lax.all_gather(co, axis), partial)
         return cj.pt_sum(cj.F1, gathered, n_devices)
 
-    from ...utils.jaxtools import shard_map_compat
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -769,12 +768,11 @@ def _rlc_kernel_sharded(n_devices: int, per_shard: int, axis: str,
         total = tw.fq12_mul(total, f_extra)
         return tw.fq12_is_one(pj.final_exponentiate(total))
 
-    from ...utils.jaxtools import shard_map_compat
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis)),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     return jax.jit(sharded)
 

@@ -108,6 +108,20 @@ def _isqrt_u64(n):
     return x
 
 
+def _u64_collective(x, reduce):
+    """`reduce` (a psum-like collective) of uint64 `x`, carried on uint32
+    lanes: the TPU's all-reduce refuses uint64 ("Supported lowering only
+    of Sum all reduce").  Each value splits into four 16-bit limbs on a
+    new leading axis, the limb sums stay exact in uint32 for up to 2**16
+    devices, and they recombine in uint64, wrapping mod 2**64 exactly as
+    a uint64 sum would."""
+    limbs = jnp.stack([((x >> U64(16 * k)) & U64(0xFFFF)).astype(jnp.uint32)
+                       for k in range(4)])
+    sums = reduce(limbs).astype(U64)
+    return (sums[0] + (sums[1] << U64(16)) + (sums[2] << U64(32))
+            + (sums[3] << U64(48)))
+
+
 def _total(x, axis_name: str | None):
     """Global sum of a (N,) shard — psum across the mesh axis if sharded.
 
@@ -116,7 +130,7 @@ def _total(x, axis_name: str | None):
     recompile-traced-branch rule keys off the annotation)."""
     s = jnp.sum(x)
     if axis_name is not None:
-        s = lax.psum(s, axis_name)
+        s = _u64_collective(s, lambda v: lax.psum(v, axis_name))
     return s
 
 
@@ -198,8 +212,8 @@ def _epoch_sweep_impl(reg: RegistryArrays, sc: EpochScalars,
             prop_contrib, mode="drop")
         # reduce-scatter: each shard receives exactly its own reduced slice
         # (no full-array broadcast back as psum would do)
-        rewards += lax.psum_scatter(
-            global_acc, axis_name, scatter_dimension=0, tiled=True)
+        rewards += _u64_collective(global_acc, lambda v: lax.psum_scatter(
+            v, axis_name, scatter_dimension=1, tiled=True))
 
     # -- inactivity-leak penalties (get_inactivity_penalty_deltas)
     leak_base = (jnp.uint64(p.base_rewards_per_epoch) * base_reward

@@ -254,7 +254,6 @@ def sharded_epoch_step(mesh: Mesh, params: EpochParams,
     size, power of two); outputs (new_bal, new_eff, balances_root,
     registry_root) with the roots replicated."""
     from . import require_x64
-    from ..utils.jaxtools import shard_map_compat
     from .merkle import (ValidatorLeaves, balances_list_root,
                          validator_records_root, validator_registry_root)
 
@@ -273,8 +272,10 @@ def sharded_epoch_step(mesh: Mesh, params: EpochParams,
         return new_bal, new_eff, bal_root, reg_root
 
     in_specs, out_specs = epoch_step_specs(axis)
-    sharded = shard_map_compat(_step, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    # replication is not checked: the roots are replicated by explicit
+    # all_gathers
+    sharded = jax.shard_map(_step, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(sharded)
 
 

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 
@@ -45,8 +44,6 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_enable_x64", True)
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     from consensus_specs_tpu.utils.jaxtools import enable_compile_cache
 
     enable_compile_cache()

@@ -313,8 +313,9 @@ def _span_stack() -> list:
 
 def _trace_annotation(name: str):
     """A `jax.profiler.TraceAnnotation` when jax is ALREADY imported in
-    this process, else None.  Importing jax from telemetry is forbidden:
-    on the TPU image, first import can claim a pooled device."""
+    this process, else None.  Telemetry never imports jax itself: the
+    bench parent that uses it must stay off JAX, so that its worker
+    processes can hold the device."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None

@@ -8,9 +8,9 @@ generated-code bytes) — joins them with the measured `run_s` from the
 compile-vs-run split, and derives the roofline numbers the ROADMAP's
 open perf questions need: achieved FLOP/s, achieved bytes/s, arithmetic
 intensity, and a compute- / memory- / launch-bound classification
-against a small per-backend peak registry (TPU peaks read from
-`BASELINE.json`'s `"peaks"` section; CPU peaks are built-in and marked
-advisory).  It also samples per-device live-buffer bytes at span
+against a small peak registry keyed by `device_kind` (TPU peaks read
+from `BASELINE.json`'s `"peaks"` section; CPU peaks are built-in and
+marked advisory).  It also samples per-device live-buffer bytes at span
 boundaries (device-memory watermarks, high-water mark kept per device).
 
 Gating contract, strictly additive to core.py's: everything here is OFF
@@ -54,15 +54,18 @@ _MAX_WM_EVENTS = 50_000
 # dispatch overhead, not by the work XLA counted: launch-bound
 LAUNCH_BOUND_FRAC = 0.05
 
-# built-in per-backend peaks; `BASELINE.json`'s "peaks" section
-# overrides per key (the README documents provenance and how to correct
-# them per TPU generation).  CPU entries are advisory: a portable CI
-# host has no single honest peak, so its utilization numbers rank
-# kernels against each other rather than against the hardware.
+# built-in peaks keyed by jax `device_kind` ("cpu" is the CPU backend's
+# kind); `BASELINE.json`'s "peaks" section overrides per key.  A device
+# kind with no row gets no peak: its kernels stay unclassified rather
+# than being measured against another chip's roofline.  CPU entries are
+# advisory: a portable CI host has no single honest peak, so its
+# utilization numbers rank kernels against each other rather than
+# against the hardware.
 _DEFAULT_PEAKS = {
-    "tpu": {"flops_per_s": 1.97e14, "bytes_per_s": 8.19e11,
-            "advisory": False,
-            "note": "TPU v5e published bf16 peak / HBM bandwidth"},
+    "TPU v5 lite": {"flops_per_s": 1.97e14, "bytes_per_s": 8.19e11,
+                    "advisory": False,
+                    "note": "TPU v5e published bf16 peak / HBM bandwidth",
+                    "source": "Google Cloud documentation, 'TPU v5e'"},
     "cpu": {"flops_per_s": 5.0e10, "bytes_per_s": 2.0e10,
             "advisory": True,
             "note": "generic CI-host estimate — advisory only"},
@@ -124,8 +127,8 @@ def _baseline_path() -> Path:
 
 
 def peaks() -> dict:
-    """The per-backend peak registry: built-in defaults overlaid with
-    `BASELINE.json`'s `"peaks"` section (per backend, per key).  A
+    """The peak registry by device kind: built-in defaults overlaid with
+    `BASELINE.json`'s `"peaks"` section (per device kind, per key).  A
     missing or malformed file degrades to the defaults — the cost model
     must never crash the path it observes."""
     global _peaks_cache
@@ -148,18 +151,24 @@ def peaks() -> dict:
     return merged
 
 
-def peaks_for(platform: str | None) -> dict | None:
-    """Peak entry for a jax platform name ('tpu', 'cpu', 'tpu v5', ...);
-    None when the registry has nothing applicable."""
-    if not platform:
+_warned_kinds: set[str] = set()
+
+
+def peaks_for(device_kind: str | None) -> dict | None:
+    """Peak entry for a jax `device_kind` ('TPU v5 lite', 'cpu', ...),
+    matched exactly (case-insensitive).  An unknown kind gets None and
+    one warning on stderr: no other chip's peaks stand in for it."""
+    if not device_kind:
         return None
     reg = peaks()
-    p = str(platform).lower()
-    for backend in sorted(reg, key=len, reverse=True):
-        if p.startswith(backend):
-            entry = dict(reg[backend])
-            entry["backend"] = backend
-            return entry
+    kind = str(device_kind)
+    for key, entry in reg.items():
+        if key.lower() == kind.lower():
+            return dict(entry, backend=key)
+    if kind not in _warned_kinds:
+        _warned_kinds.add(kind)
+        print(f"costmodel: no peaks for device kind {kind!r}; its "
+              f"kernels stay unclassified", file=sys.stderr, flush=True)
     return None
 
 
@@ -267,6 +276,7 @@ def capture(kernel: str, fn, args, kwargs=None) -> dict | None:
             rec["memory"] = mem
         if jax is not None:
             rec["platform"] = jax.devices()[0].platform
+            rec["device_kind"] = jax.devices()[0].device_kind
             t0 = time.perf_counter()
             jax.block_until_ready(fn(*args, **(kwargs or {})))
             rec["run_s_probe"] = round(time.perf_counter() - t0, 6)
@@ -281,6 +291,7 @@ def capture(kernel: str, fn, args, kwargs=None) -> dict | None:
 
 def record_cost(kernel: str, flops: float, bytes_accessed: float,
                 transcendentals: float = 0.0, platform: str = "cpu",
+                device_kind: str | None = None,
                 run_s_probe: float | None = None,
                 memory: dict | None = None) -> None:
     """Direct cost-record injection (tests and synthetic report rounds);
@@ -291,6 +302,7 @@ def record_cost(kernel: str, flops: float, bytes_accessed: float,
            "bytes_accessed": float(bytes_accessed),
            "transcendentals": float(transcendentals),
            "platform": platform,
+           "device_kind": device_kind or platform,
            "ts_rel_us": round((time.perf_counter() - core._T0) * 1e6, 1)}
     if run_s_probe is not None:
         rec["run_s_probe"] = float(run_s_probe)
@@ -439,7 +451,7 @@ def join_record(raw: dict, hists: dict) -> dict:
     comp_hist = hists.get(f"kernel.{key}.compile_first_s")
     if isinstance(comp_hist, dict) and comp_hist.get("count"):
         rec["compile_first_s"] = round(comp_hist["total"], 4)
-    peak = peaks_for(rec.get("platform"))
+    peak = peaks_for(rec.get("device_kind"))
     rec.update(classify(rec.get("flops", 0.0),
                         rec.get("bytes_accessed", 0.0),
                         rec["run_s_mean"], peak))

@@ -24,7 +24,7 @@ Record schema (version `SCHEMA`; one JSON object per line):
      "round": int,               # BENCH_rNN / MULTICHIP_rNN round number
      "file": str,                # basename the record was parsed from
      "rc": int,                  # driver wrapper return code
-     "platform": str,            # "tpu" | "cpu" | "cpu-fallback" | ...
+     "platform": str,            # jax.devices()[0].platform: "tpu" | "cpu"
      "baseline_us_per_validator": float,   # oracle fingerprint (flagship)
      "telemetry": dict,          # compact compile_s/run_s/padding/routing
      "detail": dict,             # msm break-even per-size table
@@ -114,6 +114,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 from pathlib import Path
 
 SCHEMA = 1
@@ -1148,11 +1149,16 @@ def sync_records(path, records) -> int:
 # --- live bench emissions ----------------------------------------------------
 
 
-def emission_platform() -> str:
-    """Best-effort platform stamp for a live bench emission: an explicit
-    JAX_PLATFORMS pin (the CPU smoke path sets `cpu`) wins; otherwise
-    the pooled TPU the benches default to."""
-    return os.environ.get("JAX_PLATFORMS") or "tpu"
+def emission_platform() -> str | None:
+    """Platform stamp for a live bench emission that carries none: what
+    the device of this process reports, when the process has JAX loaded
+    (bench_bls / bench_serve emit from the process that ran the work).
+    None otherwise — this module never imports JAX, and the bench.py
+    parent stamps each record from its worker's own report."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.devices()[0].platform
 
 
 # live-emission costmodel dedupe: a bench process emits one metric line

@@ -1458,8 +1458,9 @@ def main(argv=None) -> int:
         description="Benchwatch: longitudinal perf dashboard + "
                     "regression gate over bench/telemetry rounds.")
     parser.add_argument("--repo", type=Path, default=_default_repo(),
-                        help="repo root holding BENCH_r*/MULTICHIP_r* "
-                             "round files (default: this checkout)")
+                        help="directory holding driver round files "
+                             "(BENCH_r*/MULTICHIP_r*.json) and the "
+                             "oracle baselines (default: this checkout)")
     parser.add_argument("--history", type=Path, default=None,
                         help="history store path (default: "
                              "<repo>/out/bench_history.jsonl)")

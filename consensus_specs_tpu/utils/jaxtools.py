@@ -2,106 +2,57 @@
 
 from __future__ import annotations
 
+import os
+import sys
 from pathlib import Path
 
 REPO_ROOT = Path(__file__).resolve().parent.parent.parent
+DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
 
 
-def host_cache_key() -> str:
-    """Host+platform fingerprint for the compile-cache directory.  XLA:CPU
-    AOT results are machine-feature sensitive, and this repo moves between
-    machines (driver vs dev box): a shared flat cache demonstrably loaded
-    cross-machine entries (round-4 multichip log was full of 'machine
-    features ... doesn't match' warnings), and a poisoned entry can break a
-    later TPU compile.  Keying the directory by machine/cpu-count/platform
-    pin makes stale cross-host reuse structurally impossible."""
-    import hashlib
-    import os
-    import platform
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for every compile.
 
-    plat = os.environ.get("JAX_PLATFORMS", "default") or "default"
-    # machine()/cpu_count alone cannot distinguish two x86_64 hosts with
-    # different ISA extensions — hash the kernel's CPU feature flags too
-    feats = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    feats = hashlib.sha256(
-                        line.encode()).hexdigest()[:12]
-                    break
-    except OSError:
-        pass
-    return f"{platform.machine()}-{os.cpu_count()}cpu-{feats}-{plat}"
-
-
-def enable_compile_cache(cache_dir: Path | None = None) -> None:
-    """Point JAX's persistent compilation cache at a host-keyed subdir of
-    `.jax_cache/` so repeated bench / driver runs on one machine pay the
-    XLA compile once.  Failure is never fatal — the cache is an
-    optimization.  Set CST_NO_COMPILE_CACHE=1 to disable entirely (bench
-    retry path uses this to rule out cache poisoning).
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and the
+    directory is left alone; otherwise the cache lives at the fixed,
+    gitignored `.jax_cache/` of the checkout (the path is part of the
+    cache key, so it must not move between runs).  A failure to set the
+    cache up is reported on stderr and the process goes on uncached.
 
     Telemetry records the chosen directory and its entry count at setup;
     cache HITS are not observable through jax's config API, so they are
     inferred downstream from first-call latency (a hit makes the
     `kernel.compile_first_s` sample collapse toward `kernel.run_s` —
     see the README's telemetry notes)."""
-    import os
+    import jax
 
     from .. import telemetry
 
-    import jax
-
-    if os.environ.get("CST_NO_COMPILE_CACHE"):
-        telemetry.set_meta("compile_cache.dir", None)
-        return
     try:
-        d = cache_dir or (REPO_ROOT / ".jax_cache" / host_cache_key())
-        d.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(d))
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            DEFAULT_CACHE_DIR.mkdir(exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir",
+                              str(DEFAULT_CACHE_DIR))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # XLA:CPU AOT kernel caches are machine-feature sensitive beyond
-        # what /proc/cpuinfo exposes (e.g. +prefer-no-scatter target
-        # tuning): excluding them keeps cached entries loadable across
-        # toolchain tweaks and silences the cpu_aot_loader SIGILL-hazard
-        # warnings the round-4 multichip log was full of
-        jax.config.update("jax_persistent_cache_enable_xla_caches",
-                          "none")
-        if telemetry.enabled():
-            telemetry.set_meta("compile_cache.dir", str(d))
-            telemetry.set_meta("compile_cache.entries_at_start",
-                               sum(1 for p in d.iterdir() if p.is_file()))
-    except Exception:
-        pass
+    except Exception as exc:   # cst: allow(exc-swallow-device): the cache is an optimisation; the failure is reported, not hidden
+        print(f"compile cache not enabled: {type(exc).__name__}: {exc}",
+              file=sys.stderr, flush=True)
+        return
+    if telemetry.enabled():
+        d = Path(jax.config.jax_compilation_cache_dir)
+        telemetry.set_meta("compile_cache.dir", str(d))
+        telemetry.set_meta("compile_cache.entries_at_start",
+                           sum(1 for p in d.iterdir() if p.is_file())
+                           if d.is_dir() else 0)
 
 
-def backends_initialized() -> bool:
-    """True once any PJRT backend exists.  Must never *trigger* backend
-    initialization: on this image the default platform is a pooled TPU whose
-    claim can take minutes, so probing via `jax.devices()` is itself the
-    multi-minute stall this predicate exists to avoid."""
-    try:
-        from jax._src import xla_bridge
-
-        return bool(xla_bridge._backends)
-    except Exception:
-        return False
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions: the stable API (jax >= 0.6,
-    `check_vma`) when present, `jax.experimental.shard_map` (`check_rep`)
-    on older builds like this image's 0.4.x.  Replication checking is
-    disabled either way — the sharded kernels replicate reductions by
-    explicit all_gathers."""
+def device_fields() -> dict:
+    """What this process's devices report, stamped on every bench
+    result so that no number is read as another device's."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}

@@ -1,18 +1,19 @@
 """Benchwatch contract tests (`telemetry/history.py` + `report.py`).
 
-Three layers, pinned against real data wherever possible:
+Three layers:
 
-- the INGESTER, run as goldens over the checked-in `BENCH_r01..r05` /
-  `MULTICHIP_r*` round files (including the rounds that FAILED — r03
-  timed out before printing JSON, r04 died in a traceback: both must
-  skip with a counted warning, never crash) plus malformed/truncated
-  synthetic wrappers and unknown-schema history lines;
+- the INGESTER, run as goldens over small synthetic round files in the
+  driver's wrapper shape (`rounds_repo`: BENCH_r01/r03/r04/r05 and
+  MULTICHIP_r01/r05, including the rounds that FAILED — r03 timed out
+  before printing JSON, r04 died in a traceback: both must skip with a
+  counted warning, never crash) plus malformed/truncated wrappers and
+  unknown-schema history lines;
 - the TREND/GATE engine: a synthetic regression round (flagship
   `vs_baseline` halved) must make the reporter exit nonzero and NAME
   the offending metric, a clean round must exit zero, and the oracle-
   fingerprint guard must keep incomparable baselines from reading as
   regressions;
-- the REPORTER CLI on this repo's real rounds: the markdown dashboard
+- the REPORTER CLI over those rounds: the markdown dashboard
   renders trend tables for the flagship + extras metrics, evaluates
   every ROADMAP threshold, and emits the `_MSM_DEVICE_MIN`
   recommendation (the acceptance criterion for this subsystem).
@@ -36,7 +37,9 @@ FLAGSHIP = "mainnet_epoch_sweep_1m_validators_wall"
 
 def _flagship_line(value, vs_baseline, platform="tpu", extra=None):
     obj = {"metric": FLAGSHIP, "value": value, "unit": "s",
-           "vs_baseline": vs_baseline, "platform": platform}
+           "vs_baseline": vs_baseline}
+    if platform is not None:
+        obj["platform"] = platform
     if extra:
         obj["extra"] = extra
     return json.dumps(obj)
@@ -49,11 +52,61 @@ def _round_file(tmp_path, n, tail, rc=0):
     return path
 
 
-# --- golden ingestion over the checked-in rounds -----------------------------
+# --- golden ingestion over synthetic rounds ----------------------------------
+
+_R05_EXTRAS = {
+    "attestation_batch_128x64_verify_wall":
+        {"value": 4.578, "unit": "s", "vs_baseline": 9.9},
+    "sync_aggregate_512_verify_wall":
+        {"value": 0.1987, "unit": "s", "vs_baseline": 1.7},
+    "blob_kzg_proof_batch_6_verify_wall":
+        {"value": 1.1967, "unit": "s", "vs_baseline": 0.9},
+    "minimal_phase0_state_transition_signed_block_wall":
+        {"value": 0.8547, "unit": "s", "vs_baseline": 1.1},
+}
 
 
-def test_golden_round_01_flagship_and_fingerprint():
-    records, warnings = history.parse_bench_round(REPO / "BENCH_r01.json")
+@pytest.fixture(scope="module")
+def rounds_repo(tmp_path_factory):
+    """A repo root holding driver round files of every shape the ingester
+    meets: a flagship round with a fresh oracle measure (r01), a round
+    cut by the driver's timeout before any JSON (r03), one that died in
+    a traceback (r04), a flagship + extras round (r05), a failed and a
+    passing multichip dryrun, and the two checked-in oracle baselines."""
+    root = tmp_path_factory.mktemp("rounds")
+    _round_file(root, 1, "\n".join([
+        "baseline: 77.622s @ 1024 validators (75802.3 us/validator)",
+        "tpu: compile+first run 73.8s on TPU v5 lite0",
+        "tpu: 3673.9 ms/step @ 1048576 validators",
+        _flagship_line(3.6739, 21634.7, platform=None)]))
+    _round_file(root, 3, "baseline: 95.099s @ 1024 validators "
+                         "(92870.5 us/validator)\n", rc=124)
+    _round_file(root, 4, "Traceback (most recent call last):\n"
+                         "RuntimeError: Unable to initialize backend "
+                         "'tpu': UNAVAILABLE\n", rc=1)
+    _round_file(root, 5, "\n".join([
+        "baseline (persisted 2026-07-29): 244.6 us/validator @ 1024 "
+        "validators",
+        _flagship_line(3.3903, 75.7),
+        "--- bls extras (elapsed 43s) ---",
+        "attestation batch compile+first: 81.1s",
+        "sync aggregate compile+first: 16.6s",
+        "--- kzg extras (elapsed 166s) ---",
+        "kzg batch device compile+first: 16.9s",
+        _flagship_line(3.3903, 75.7, extra=_R05_EXTRAS)]))
+    for n, rc, tail in ((1, 1, "RuntimeError: need 8 devices, have 1"),
+                        (5, 0, "")):
+        (root / f"MULTICHIP_r{n:02d}.json").write_text(json.dumps(
+            {"n_devices": 8, "rc": rc, "ok": rc == 0, "skipped": False,
+             "tail": tail}))
+    for name in ("bench_baseline.json", "bench_bls_baseline.json"):
+        (root / name).write_text((REPO / name).read_text())
+    return root
+
+
+def test_golden_round_01_flagship_and_fingerprint(rounds_repo):
+    records, warnings = history.parse_bench_round(
+        rounds_repo / "BENCH_r01.json")
     assert not warnings
     by_metric = {r["metric"]: r for r in records}
     flag = by_metric[FLAGSHIP]
@@ -68,8 +121,9 @@ def test_golden_round_01_flagship_and_fingerprint():
         assert not history.validate_record(rec), rec
 
 
-def test_golden_round_05_extras_flattened():
-    records, warnings = history.parse_bench_round(REPO / "BENCH_r05.json")
+def test_golden_round_05_extras_flattened(rounds_repo):
+    records, warnings = history.parse_bench_round(
+        rounds_repo / "BENCH_r05.json")
     assert not warnings
     by_metric = {r["metric"]: r for r in records}
     assert by_metric[FLAGSHIP]["value"] == 3.3903
@@ -90,18 +144,20 @@ def test_golden_round_05_extras_flattened():
 
 @pytest.mark.parametrize("name,rc", [("BENCH_r03.json", 124),
                                      ("BENCH_r04.json", 1)])
-def test_golden_failed_rounds_skip_with_warning(name, rc):
+def test_golden_failed_rounds_skip_with_warning(rounds_repo, name, rc):
     """r03 timed out before printing JSON, r04 died in a traceback —
     the exact inputs the ingester must survive."""
-    records, warnings = history.parse_bench_round(REPO / name)
+    records, warnings = history.parse_bench_round(rounds_repo / name)
     assert records == []
     assert len(warnings) == 1
     assert f"rc={rc}" in warnings[0] and "skipped" in warnings[0]
 
 
-def test_golden_multichip_rounds():
-    recs1, w1 = history.parse_multichip_round(REPO / "MULTICHIP_r01.json")
-    recs5, w5 = history.parse_multichip_round(REPO / "MULTICHIP_r05.json")
+def test_golden_multichip_rounds(rounds_repo):
+    recs1, w1 = history.parse_multichip_round(
+        rounds_repo / "MULTICHIP_r01.json")
+    recs5, w5 = history.parse_multichip_round(
+        rounds_repo / "MULTICHIP_r05.json")
     assert not w1 and not w5
     assert recs1[0]["metric"] == "multichip_dryrun_ok"
     assert recs1[0]["value"] == 0.0 and recs1[0]["rc"] == 1
@@ -120,8 +176,8 @@ def test_golden_oracle_baselines():
         "oracle_fast_aggregate_verify_s", "oracle_sync_aggregate_verify_s"}
 
 
-def test_ingest_repo_full_sweep():
-    records, warnings = history.ingest_repo(REPO)
+def test_ingest_repo_full_sweep(rounds_repo):
+    records, warnings = history.ingest_repo(rounds_repo)
     # r03 + r04 are the only expected casualties
     assert len(warnings) == 2
     metrics = {r["metric"] for r in records}
@@ -177,9 +233,9 @@ def test_history_unknown_schema_version_skipped(tmp_path):
     assert any("malformed" in w for w in warnings)
 
 
-def test_sync_records_is_idempotent(tmp_path):
+def test_sync_records_is_idempotent(tmp_path, rounds_repo):
     store = tmp_path / "h.jsonl"
-    records, _ = history.ingest_repo(REPO)
+    records, _ = history.ingest_repo(rounds_repo)
     n1 = history.sync_records(store, records)
     n2 = history.sync_records(store, records)
     assert n1 == len(records) and n2 == 0
@@ -295,8 +351,8 @@ def test_thresholds_tpu_only_ignores_cpu_smoke():
     assert rows["attestation-speedup"]["status"] == "no data"
 
 
-def test_thresholds_evaluated_on_checked_in_rounds(tmp_path):
-    records, _ = history.ingest_repo(REPO)
+def test_thresholds_evaluated_on_rounds(rounds_repo):
+    records, _ = history.ingest_repo(rounds_repo)
     rows = {t["id"]: t for t in report.evaluate_thresholds(records)}
     # ROADMAP state as of round 5: all three speedups below target,
     # compile+first over budget, multichip healthy
@@ -349,8 +405,8 @@ def test_incomparable_oracles_fall_back_to_wall(tmp_path):
     assert len(regs) == 1 and regs[0]["kind"] == "wall"
 
 
-def test_checked_in_rounds_have_no_regression():
-    records, _ = history.ingest_repo(REPO)
+def test_rounds_have_no_regression(rounds_repo):
+    records, _ = history.ingest_repo(rounds_repo)
     assert report.find_regressions(records, max_regress_pct=20.0) == []
 
 
@@ -384,8 +440,8 @@ def test_msm_recommendation_keeps_threshold_without_device_win():
     assert "keep 16" in msm["text"]
 
 
-def test_msm_recommendation_no_data():
-    records, _ = history.ingest_repo(REPO)   # no probe rows checked in yet
+def test_msm_recommendation_no_data(rounds_repo):
+    records, _ = history.ingest_repo(rounds_repo)   # no probe rows
     assert report.msm_recommendation(records)["status"] == "no data"
 
 
@@ -400,14 +456,15 @@ def _run_cli(tmp_path, repo, *extra):
         *extra])
 
 
-def test_cli_dashboard_on_checked_in_rounds(tmp_path, monkeypatch, capsys):
-    """The acceptance criterion: offline over the real rounds, the
+def test_cli_dashboard_on_rounds(tmp_path, rounds_repo, monkeypatch,
+                                capsys):
+    """The acceptance criterion: offline over the rounds, the
     dashboard renders trends for flagship + extras, evaluates every
     ROADMAP threshold, and exits zero (unmet targets are advisory; no
     round-over-round regression)."""
     monkeypatch.delenv("CST_BENCHWATCH_STRICT", raising=False)
     monkeypatch.delenv("CST_BENCHWATCH_MAX_REGRESS_PCT", raising=False)
-    rc = _run_cli(tmp_path, REPO, "--json", str(tmp_path / "r.json"))
+    rc = _run_cli(tmp_path, rounds_repo, "--json", str(tmp_path / "r.json"))
     assert rc == 0
     text = (tmp_path / "report.md").read_text()
     for metric in (FLAGSHIP, "attestation_batch_128x64_verify_wall",
@@ -427,7 +484,7 @@ def test_cli_dashboard_on_checked_in_rounds(tmp_path, monkeypatch, capsys):
         == {t["id"] for t in report.THRESHOLDS}
     # second run: fully deduped against the store
     capsys.readouterr()
-    assert _run_cli(tmp_path, REPO) == 0
+    assert _run_cli(tmp_path, rounds_repo) == 0
     assert "(0 new this run)" in capsys.readouterr().out
 
 
@@ -458,11 +515,12 @@ def test_cli_clean_round_exits_zero(tmp_path, monkeypatch):
     assert _run_cli(tmp_path, repo) == 0
 
 
-def test_cli_strict_mode_gates_on_thresholds(tmp_path, monkeypatch):
+def test_cli_strict_mode_gates_on_thresholds(tmp_path, rounds_repo,
+                                             monkeypatch):
     """--strict promotes the unmet ROADMAP targets (round 5 is below
     every speedup target) to exit-code failures."""
     monkeypatch.delenv("CST_BENCHWATCH_MAX_REGRESS_PCT", raising=False)
-    assert _run_cli(tmp_path, REPO, "--strict") == 1
+    assert _run_cli(tmp_path, rounds_repo, "--strict") == 1
 
 
 def test_cli_attribution_from_snapshot(tmp_path, monkeypatch, capsys):

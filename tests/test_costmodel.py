@@ -89,11 +89,11 @@ def test_capture_failure_stores_error_record_never_raises():
 def test_record_cost_direct_injection():
     costmodel.record_cost("synthetic@4", flops=100.0,
                           bytes_accessed=10.0, platform="tpu",
-                          run_s_probe=1.0)
+                          device_kind="TPU v5 lite", run_s_probe=1.0)
     blk = costmodel.block()
     rec = blk["kernels"]["synthetic@4"]
     assert rec["bound"] in ("compute", "memory", "launch")
-    assert rec["peak_source"] == "tpu"
+    assert rec["peak_source"] == "TPU v5 lite"
 
 
 # --- classification boundaries ----------------------------------------------
@@ -143,11 +143,26 @@ def test_classify_without_peak_or_run_is_unknown():
 
 def test_peaks_registry_reads_baseline_json():
     reg = costmodel.peaks()
-    assert reg["tpu"]["flops_per_s"] > 0
+    assert reg["TPU v5 lite"]["flops_per_s"] > 0
+    assert "Google Cloud" in reg["TPU v5 lite"]["source"]
     assert reg["cpu"]["advisory"] is True
-    entry = costmodel.peaks_for("tpu v5 lite")
-    assert entry and entry["backend"] == "tpu"
-    assert costmodel.peaks_for("quantum") is None
+    entry = costmodel.peaks_for("TPU v5 lite")
+    assert entry and entry["backend"] == "TPU v5 lite"
+    assert costmodel.peaks_for("cpu")["advisory"] is True
+
+
+@pytest.mark.parametrize("kind", ["TPU v6 lite", "TPU v4", "tpu", "quantum"])
+def test_peaks_for_unknown_device_kind_is_none_and_warns(kind, capsys):
+    # no other chip's row stands in: a v5e prefix match would price a
+    # different generation's kernels against the v5e roofline
+    costmodel._warned_kinds.discard(kind)
+    assert costmodel.peaks_for(kind) is None
+    assert kind in capsys.readouterr().err
+    costmodel.record_cost("unknown@1", flops=1.0, bytes_accessed=1.0,
+                          platform="tpu", device_kind=kind,
+                          run_s_probe=1.0)
+    rec = costmodel.block()["kernels"]["unknown@1"]
+    assert rec["bound"] == "unknown" and "peak_source" not in rec
 
 
 # --- watermarks -------------------------------------------------------------
