@@ -133,34 +133,7 @@ def _worker_setup_jax():
     from consensus_specs_tpu.utils.jaxtools import enable_compile_cache
 
     enable_compile_cache()
-
-    # CST_PROFILE=<dir>: capture a jax profiler trace of the worker
-    # (TensorBoard-loadable; the tracing hook SURVEY §5.1 calls for)
-    profile_dir = os.environ.get("CST_PROFILE")
-    if profile_dir:
-        import atexit
-
-        jax.profiler.start_trace(profile_dir)
-        log(f"profiler trace -> {profile_dir}")
-        # atexit alone would lose the trace when the driver's subprocess
-        # timeout kills the worker — workers also call
-        # _stop_profile_trace() right after their measured section
-        atexit.register(_stop_profile_trace)
     return jax
-
-
-_profile_stopped = False
-
-
-def _stop_profile_trace():
-    """Flush the CST_PROFILE trace (idempotent; no-op when disabled)."""
-    global _profile_stopped
-    if not os.environ.get("CST_PROFILE") or _profile_stopped:
-        return
-    _profile_stopped = True
-    import jax
-
-    jax.profiler.stop_trace()
 
 
 def worker_epoch(n: int) -> None:
@@ -330,7 +303,6 @@ def worker_epoch(n: int) -> None:
     telemetry.costmodel.sample_watermark("bench.epoch.steady")
     log(f"{dt * 1e3:.1f} ms/step @ {n} validators "
         f"({parity_checks} parity check(s) ok, root {out[3][:2]})")
-    _stop_profile_trace()
     result = {"seconds": dt, **device_fields(),
               "dirty_frac": frac, "dirty_validators": int(m),
               "parity_checks": parity_checks}
@@ -449,7 +421,6 @@ def worker_merkle() -> None:
         for k in list(out)[1:]:
             out[k].pop("telemetry", None)
     out.update(device_fields())
-    _stop_profile_trace()
     print(json.dumps(out), flush=True)
 
 
@@ -588,7 +559,6 @@ def worker_scaling() -> None:
     assert block["rungs"], "no scaling rung completed"
     telemetry.costmodel.sample_watermark("bench.scaling")
     top = block["rungs"][-1]
-    _stop_profile_trace()
     out = {"flagship_scaling": {
         "value": top["per_chip_vps"], "unit": "validators/s/chip",
         "vs_baseline": top["efficiency"], "scaling": block}}
@@ -863,7 +833,6 @@ def worker_das() -> None:
     finally:
         bls.bls_active = prev_active
     out.update(device_fields())
-    _stop_profile_trace()
     print(json.dumps(out), flush=True)
 
 
@@ -1001,7 +970,6 @@ def worker_forkchoice() -> None:
         out[f"forkchoice_lmd_ghost_{n_blocks}x{n_validators}"
             f"_head_wall"] = rec
     out.update(device_fields())
-    _stop_profile_trace()
     print(json.dumps(out), flush=True)
 
 
@@ -1075,7 +1043,6 @@ def worker_bls() -> None:
         except Exception as e:
             out["g1_msm_breakeven_probe_error"] = repr(e)[:300]
 
-    _stop_profile_trace()
     out.update(device_fields())
     print(json.dumps(out), flush=True)
 
@@ -1131,7 +1098,6 @@ def worker_kzg() -> None:
         f"{time.perf_counter() - first:.1f}s")
     dev_dt = measure()
 
-    _stop_profile_trace()
     kzg = telemetry.embed_bench_block(
         {"value": round(dev_dt, 4), "unit": "s",
          "vs_baseline": round(py_dt / dev_dt, 1)})
@@ -1186,7 +1152,6 @@ def worker_spec() -> None:
     transition_one(state.copy())  # compile
     dev_dt = measure()
 
-    _stop_profile_trace()
     rec = telemetry.embed_bench_block(
         {"value": round(dev_dt, 4), "unit": "s",
          "vs_baseline": round(py_dt / dev_dt, 1)})

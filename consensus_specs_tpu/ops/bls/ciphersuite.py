@@ -9,6 +9,7 @@ and the G1/G2 byte converters.
 
 from __future__ import annotations
 
+from ... import telemetry
 from .curve import (
     G1_GEN,
     G2_GEN,
@@ -48,15 +49,23 @@ def KeyValidate(pubkey: bytes) -> bool:
 
 
 def _sig_to_point(signature: bytes):
-    p = g2_from_bytes(signature)
-    if not subgroup_check_g2(p):
+    with telemetry.span("bls.decompress_g2"):
+        p = g2_from_bytes(signature)
+    with telemetry.span("bls.subgroup_g2"):
+        in_subgroup = subgroup_check_g2(p)
+    if not in_subgroup:
         raise ValueError("signature not in G2 subgroup")
     return p
 
 
 def _pk_to_point(pubkey: bytes):
-    p = g1_from_bytes(pubkey)
-    if g1.is_inf(p) or not subgroup_check_g1(p):
+    with telemetry.span("bls.decompress_g1"):
+        p = g1_from_bytes(pubkey)
+    if g1.is_inf(p):
+        raise ValueError("invalid pubkey")
+    with telemetry.span("bls.subgroup_g1"):
+        in_subgroup = subgroup_check_g1(p)
+    if not in_subgroup:
         raise ValueError("invalid pubkey")
     return p
 

@@ -75,6 +75,7 @@ from ... import telemetry
 from ...resilience import faults
 from ...serve.futures import DeviceFuture, bool_future, value_future
 from ...telemetry import costmodel, occupancy
+from ...utils.jaxtools import jit
 from ..bls import curve as _pycurve
 from ..bls.hash_to_curve import DST_G2, hash_to_g2
 from . import curve_jax as cj
@@ -219,12 +220,10 @@ def g2_to_affine_dev(p):
 
 @functools.lru_cache(maxsize=16)
 def _pairing_check_precomp_fn(batch: int):
-    import jax
-
-    def run(xp, yp, lines, mask):
+    def pairing_check(xp, yp, lines, mask):
         return pj.multi_pairing_check_precomp(xp, yp, lines, mask)
 
-    return jax.jit(run)
+    return jit(pairing_check)
 
 
 def pairing_check_device_async(pairs, block: bool = True) -> DeviceFuture:
@@ -282,46 +281,50 @@ def _rlc_pairing_core(pk_x, pk_y, sig_x, sig_y, h_x, h_y, h_ok,
     """Traced body shared by the host-hash and device-hash RLC kernels:
     scalar-mul the B pubkeys and signatures by the random coefficients,
     sum the signature side, run the B+1 pairing product with the shared
-    Fq12 accumulator."""
+    Fq12 accumulator.  Each stage runs under a `cst.rlc.*` named scope."""
+    import jax
     jnp = _jnp()
     B = pk_x.shape[0]
     neg_g1 = cj.g1_affine_to_limbs([_pycurve.g1.neg(_pycurve.G1_GEN)])
-    one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
-                            pk_x.shape).astype(jnp.int32)
-    one2 = jnp.broadcast_to(jnp.asarray(tw.FQ2_ONE_L),
-                            sig_x.shape).astype(jnp.int32)
+    with jax.named_scope("cst.rlc.scalar_mul"):
+        one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
+                                pk_x.shape).astype(jnp.int32)
+        one2 = jnp.broadcast_to(jnp.asarray(tw.FQ2_ONE_L),
+                                sig_x.shape).astype(jnp.int32)
+        r_pk = cj.pt_scalar_mul(cj.F1, (pk_x, pk_y, one1), r_bits)
+        r_sig = cj.pt_scalar_mul(cj.F2, (sig_x, sig_y, one2), r_bits)
+    with jax.named_scope("cst.rlc.sig_sum"):
+        # padding lanes -> infinity so they vanish from the signature sum
+        r_sig = cj.pt_select(cj.F2, mask, r_sig,
+                             cj.pt_infinity(cj.F2, r_sig))
+        sum_sig = cj.pt_sum(cj.F2, r_sig, B)
+        apx, apy, a_inf = g1_to_affine_dev(r_pk)
+        sx, sy, s_inf = g2_to_affine_dev(tuple(c[None] for c in sum_sig))
 
-    r_pk = cj.pt_scalar_mul(cj.F1, (pk_x, pk_y, one1), r_bits)
-    r_sig = cj.pt_scalar_mul(cj.F2, (sig_x, sig_y, one2), r_bits)
-    # padding lanes -> infinity so they vanish from the signature sum
-    r_sig = cj.pt_select(cj.F2, mask, r_sig,
-                         cj.pt_infinity(cj.F2, r_sig))
-    sum_sig = cj.pt_sum(cj.F2, r_sig, B)
-
-    apx, apy, a_inf = g1_to_affine_dev(r_pk)
-    sx, sy, s_inf = g2_to_affine_dev(tuple(c[None] for c in sum_sig))
-
-    # pairing lanes: (r_i PK_i, H_i) for live i, plus (-G1, sum_sig)
-    xp = jnp.concatenate([apx, jnp.asarray(neg_g1[0])])
-    yp = jnp.concatenate([apy, jnp.asarray(neg_g1[1])])
-    xq = jnp.concatenate([h_x, sx])
-    yq = jnp.concatenate([h_y, sy])
-    lane_mask = jnp.concatenate([mask & ~a_inf & h_ok, ~s_inf])
-    return pj.multi_pairing_check(xp, yp, xq, yq, lane_mask)
+    # pairing lanes: (r_i PK_i, H_i) for live i, plus (-G1, sum_sig),
+    # as `pj.multi_pairing_check` runs them
+    with jax.named_scope("cst.rlc.miller_loop"):
+        xp = jnp.concatenate([apx, jnp.asarray(neg_g1[0])])
+        yp = jnp.concatenate([apy, jnp.asarray(neg_g1[1])])
+        xq = jnp.concatenate([h_x, sx])
+        yq = jnp.concatenate([h_y, sy])
+        lane_mask = jnp.concatenate([mask & ~a_inf & h_ok, ~s_inf])
+        total = pj.miller_product_batch(xp, yp, xq, yq, lane_mask)
+    with jax.named_scope("cst.rlc.final_exp"):
+        return tw.fq12_is_one(pj.final_exponentiate(total))
 
 
 @functools.lru_cache(maxsize=16)
 def _rlc_kernel(batch: int):
     """Jitted RLC kernel, message hashes computed on host."""
-    import jax
     jnp = _jnp()
 
-    def run(pk_x, pk_y, sig_x, sig_y, h_x, h_y, r_bits, mask):
+    def rlc_verify(pk_x, pk_y, sig_x, sig_y, h_x, h_y, r_bits, mask):
         h_ok = jnp.ones(pk_x.shape[0], dtype=bool)
         return _rlc_pairing_core(pk_x, pk_y, sig_x, sig_y, h_x, h_y,
                                  h_ok, r_bits, mask)
 
-    return jax.jit(run)
+    return jit(rlc_verify)
 
 
 @functools.lru_cache(maxsize=16)
@@ -331,16 +334,17 @@ def _rlc_kernel_h2c(batch: int):
     expand_message_xmd, SVDW map, cofactor clearing, scalar muls,
     pairings — runs in one device program."""
     import jax
-    jnp = _jnp()
+
     from . import h2c_jax as h2c
 
-    def run(pk_x, pk_y, sig_x, sig_y, msg_words, r_bits, mask):
-        H = h2c.hash_to_g2_dev(msg_words)
-        h_x, h_y, h_inf = g2_to_affine_dev(H)
+    def rlc_verify_h2c(pk_x, pk_y, sig_x, sig_y, msg_words, r_bits, mask):
+        with jax.named_scope("cst.rlc.h2c"):
+            H = h2c.hash_to_g2_dev(msg_words)
+            h_x, h_y, h_inf = g2_to_affine_dev(H)
         return _rlc_pairing_core(pk_x, pk_y, sig_x, sig_y, h_x, h_y,
                                  ~h_inf, r_bits, mask)
 
-    return jax.jit(run)
+    return jit(rlc_verify_h2c)
 
 
 @functools.lru_cache(maxsize=16)
@@ -348,10 +352,9 @@ def _msm_kernel(batch: int):
     """Jitted G1 MSM: batched 255-step double-and-add over all points at
     once, then a log-depth tree sum.  Fully uniform control flow; kept as
     the reference kernel and the `CST_MSM_ALGO=double-add` fallback."""
-    import jax
     jnp = _jnp()
 
-    def run(x, y, bits, mask):
+    def msm(x, y, bits, mask):
         B = x.shape[0]
         one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
                                 x.shape).astype(jnp.int32)
@@ -360,7 +363,7 @@ def _msm_kernel(batch: int):
                             cj.pt_infinity(cj.F1, muls))
         return cj.pt_sum(cj.F1, muls, B)
 
-    return jax.jit(run)
+    return jit(msm)
 
 
 @functools.lru_cache(maxsize=16)
@@ -371,15 +374,14 @@ def _msm_pippenger_kernel(batch: int, c: int):
     point-add work B + 2^(c+1) + 255/c instead of 255 doubles + adds per
     scalar.  Zero scalars (and padding lanes) land in bucket 0, which the
     reduction skips, so no mask input is needed."""
-    import jax
     jnp = _jnp()
 
-    def run(x, y, digits):
+    def msm_pippenger(x, y, digits):
         one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
                                 x.shape).astype(jnp.int32)
         return cj.pt_msm_pippenger(cj.F1, (x, y, one1), digits, c)
 
-    return jax.jit(run)
+    return jit(msm_pippenger)
 
 
 SCALAR_BITS = 255  # BLS12-381 subgroup order is 255 bits
@@ -504,7 +506,7 @@ def _msm_sharded_kernel(n_devices: int, per_shard: int, c: int,
     mesh = build_mesh(n_devices=n_devices, device_ids=device_ids,
                       axis=axis)
 
-    def local(x, y, digits):
+    def msm_sharded(x, y, digits):
         one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
                                 x.shape).astype(jnp.int32)
         partial = cj.pt_msm_pippenger(cj.F1, (x, y, one1), digits, c)
@@ -513,9 +515,9 @@ def _msm_sharded_kernel(n_devices: int, per_shard: int, c: int,
         return cj.pt_sum(cj.F1, gathered, n_devices)
 
     sharded = jax.shard_map(
-        local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
+        msm_sharded, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(), check_vma=False)
-    return jax.jit(sharded)
+    return jit(sharded)
 
 
 def g1_multi_exp_sharded_async(points, scalars,
@@ -676,8 +678,9 @@ def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
     with telemetry.span("bls.batch_verify", tasks=len(tasks),
                         device_h2c=device_h2c):
         telemetry.count("bls.batch_verify.calls")
-        arrays, n = _prepare_rlc_inputs(tasks, rand, None,
-                                        device_h2c=device_h2c)
+        with telemetry.span("bls.prepare"):
+            arrays, n = _prepare_rlc_inputs(tasks, rand, None,
+                                            device_h2c=device_h2c)
         if arrays is None:
             # degenerate path: trivial skip or the per-task host
             # fallback — no statements reached the batched kernel
@@ -694,8 +697,10 @@ def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
         _count_lanes(n, B)
         kernel = _rlc_kernel_h2c if device_h2c else _rlc_kernel
         name = f"rlc_{'h2c' if device_h2c else 'host_hash'}@{B}"
-        out = _dispatch(name, kernel(B),
-                        tuple(jnp.asarray(a) for a in arrays), block=block)
+        with telemetry.span("bls.enqueue"):
+            out = _dispatch(name, kernel(B),
+                            tuple(jnp.asarray(a) for a in arrays),
+                            block=block)
     return bool_future(out)
 
 
@@ -734,7 +739,8 @@ def _rlc_kernel_sharded(n_devices: int, per_shard: int, axis: str,
                       axis=axis)
     neg_g1 = cj.g1_affine_to_limbs([_pycurve.g1.neg(_pycurve.G1_GEN)])
 
-    def local(pk_x, pk_y, sig_x, sig_y, h_x, h_y, r_bits, mask):
+    def rlc_verify_sharded(pk_x, pk_y, sig_x, sig_y, h_x, h_y, r_bits,
+                           mask):
         B = pk_x.shape[0]   # per-shard lanes
         one1 = jnp.broadcast_to(jnp.asarray(_fq.ONE_MONT),
                                 pk_x.shape).astype(jnp.int32)
@@ -769,12 +775,12 @@ def _rlc_kernel_sharded(n_devices: int, per_shard: int, axis: str,
         return tw.fq12_is_one(pj.final_exponentiate(total))
 
     sharded = jax.shard_map(
-        local, mesh=mesh,
+        rlc_verify_sharded, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis)),
         out_specs=P(), check_vma=False,
     )
-    return jax.jit(sharded)
+    return jit(sharded)
 
 
 def batch_verify_sharded_async(tasks, n_devices: int | None = None,

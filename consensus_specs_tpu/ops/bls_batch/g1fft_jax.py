@@ -39,6 +39,7 @@ import functools
 import numpy as np
 
 from ... import telemetry
+from ...utils.jaxtools import jit
 from ..bls import curve as _pycurve
 from ..fr_batch import R_MODULUS
 from . import curve_jax as cj
@@ -188,14 +189,14 @@ def _g1_fft_kernel(n: int, batch: int, inverse: bool):
                       p, (u_idx,) * 3, (v_idx,) * 3, plus, minus))
         return p, None
 
-    def run(x, y, z):
+    def g1_fft(x, y, z):
         xs = tuple(jnp.asarray(a) for a in plan)
         p, _ = jax.lax.scan(stage, (x, y, z), xs)
         if inv_bits is not None:
             p = cj.pt_scalar_mul_const(cj.F1, p, inv_bits)
         return p
 
-    return jax.jit(run)
+    return jit(g1_fft)
 
 
 @functools.lru_cache(maxsize=4)
@@ -208,7 +209,7 @@ def _fk20_hext_kernel(n_residues: int, width: int):
     which the reduction skips."""
     import jax
 
-    def run(x, y, z, digits):
+    def fk20_hext(x, y, z, digits):
         # x/y/z: (n_residues, width, 33); digits: (n_residues, width, W)
         def one(xx, yy, zz, dd):
             return cj.pt_msm_pippenger(cj.F1, (xx, yy, zz), dd,
@@ -216,7 +217,7 @@ def _fk20_hext_kernel(n_residues: int, width: int):
 
         return jax.vmap(one, in_axes=(1, 1, 1, 1))(x, y, z, digits)
 
-    return jax.jit(run)
+    return jit(fk20_hext)
 
 
 # --- host conversions --------------------------------------------------------

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import jax
 
+from ..utils.jaxtools import watch_builds
 from .bridge import (  # noqa: F401
     pad_pow2,
     participation_from_pending,
@@ -122,6 +123,7 @@ def make_epoch_step(params: EpochParams):
     is the true validator count (for the SSZ length mix-in).
     """
     require_x64()
+    watch_builds()
 
     @jax.jit
     def step(reg: RegistryArrays, sc: EpochScalars, length):
@@ -141,6 +143,8 @@ def make_sharded_epoch_step(mesh, params: EpochParams,
     Inputs are sharded (N,) arrays (N divisible by mesh size, power of
     two); `pubkey_root`/`credentials` are the (N, 8) static leaf words.
     Outputs: (new_bal, new_eff, balances_root, registry_root) with the
-    roots replicated.
+    roots replicated.  The build ledger (`utils.jaxtools.builds`) watches
+    from here on.
     """
+    watch_builds()
     return sharded_epoch_step(mesh, params, axis=axis)

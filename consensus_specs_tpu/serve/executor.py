@@ -344,7 +344,8 @@ class ServeExecutor:
         Invalid inputs settle False immediately."""
         from ..ops.bls.ciphersuite import parse_fast_aggregate_task
 
-        task = parse_fast_aggregate_task(pubkeys, message, signature)
+        with telemetry.span("serve.parse"):
+            task = parse_fast_aggregate_task(pubkeys, message, signature)
         if task is None:
             telemetry.count("serve.rejected_eager")
             return DeviceFuture.settled(False)

@@ -9,7 +9,8 @@ registry rather than a single end-to-end number — the decomposition-
 first methodology of the committee-signature measurement literature
 (arXiv:2302.00418, arXiv:2602.06655).
 
-Gates (all collection OFF by default, disabled paths are a flag check):
+Gates (all collection OFF by default, disabled paths are a flag check
+and, for spans, one check of the profiler's state):
 
     CST_TELEMETRY=1       collect spans/counters/histograms in-process
     CST_TRACE_FILE=f.json also write a Chrome trace-event file at exit
@@ -18,9 +19,12 @@ Gates (all collection OFF by default, disabled paths are a flag check):
 Surface:
 
     span(name, **attrs)   nestable wall-clock section (ctx manager);
-                          passes through to jax.profiler.TraceAnnotation
-                          when jax is live, so the same names appear in
-                          XLA device profiles
+                          while a JAX profiler session records it is a
+                          jax.profiler.TraceAnnotation `cst.<name>`,
+                          registry on or off, so the same names appear
+                          in the profiler's trace beside the device
+    profiled_spans()      count and total of the spans closed while a
+                          profiler session recorded
     count(name, n=1)      monotonic counter
     observe(name, v)      histogram sample (count/total/min/max)
     gauge(name, v)        level sample (serve queue depth, in-flight
@@ -87,6 +91,7 @@ from .core import (
     first_call,
     gauge,
     observe,
+    profiled_spans,
     reset,
     set_meta,
     snapshot,
@@ -117,7 +122,8 @@ from .export import (
 __all__ = [
     "add_event", "configure", "costmodel", "count", "counter_value",
     "enabled", "first_call", "flightrec", "gauge", "metrics_export",
-    "monitor", "observe", "occupancy", "reqtrace", "reset",
+    "monitor", "observe", "occupancy", "profiled_spans", "reqtrace",
+    "reset",
     "set_meta",
     "snapshot", "span", "span_seconds", "bench_block", "chrome_trace",
     "embed_bench_block", "validate_bench_block",

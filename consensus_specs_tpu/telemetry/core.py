@@ -8,15 +8,21 @@ manager and `count()`/`observe()` are a single global-flag check.  The
 hot path (per-kernel dispatch in `ops.bls_batch`) therefore instruments
 unconditionally and lets this module decide.
 
+A span has two halves with separate gates.  The profiler half: while a
+JAX profiler session records, every span enters a
+`jax.profiler.TraceAnnotation` named `cst.<span name>`, registry on or
+off, so the program's sections land in the profiler's trace on the
+clock it aligns with the device; its count and total are kept too
+(`profiled_spans()`).  With no session and the registry off a span is
+one check of the profiler's state and the shared no-op object.  We
+never import jax ourselves: a telemetry layer must not initialize a
+backend.
+
 Enabled, the registry is a process singleton guarded by one lock:
 
 - spans     nestable wall-time sections (thread-local nesting stack),
             aggregated by name and appended to a bounded trace-event
-            buffer for the Chrome/Perfetto exporter; when jax is already
-            imported, each span also enters a
-            `jax.profiler.TraceAnnotation` so the same names line up in
-            XLA device profiles (we never import jax ourselves — a
-            telemetry layer must not initialize a backend).
+            buffer for the Chrome/Perfetto exporter.
 - counters  monotonically increasing ints (routing decisions, lane
             accounting, cache stats).
 - histograms count/total/min/max summaries of float samples (kernel
@@ -56,6 +62,9 @@ _first_keys: set[str] = set()
 _gauges: dict[str, dict] = {}
 _gauge_events: list[dict] = []
 _gauge_events_dropped = 0
+# {span name: [count, total_s]} of the spans that opened and closed while
+# a profiler session recorded
+_profiled: dict[str, list] = {}
 
 
 def _env_enabled() -> bool:
@@ -113,9 +122,9 @@ def reset(full: bool = False) -> None:
     (compile attribution is per-process — a kernel compiled during one
     config must not be re-counted as a compile by the next), and the
     meta entries (cache dir etc., recorded once at setup and owed to
-    every config's export).  `full=True` wipes those too (test
-    isolation).  The enabled flag and trace-file arming are always
-    unaffected."""
+    every config's export), and the profiled-span totals.  `full=True`
+    wipes those too (test isolation).  The enabled flag and trace-file
+    arming are always unaffected."""
     global _events_dropped, _gauge_events_dropped
     with _lock:
         _counters.clear()
@@ -126,6 +135,7 @@ def reset(full: bool = False) -> None:
             _meta.clear()
             _events.clear()
             _first_keys.clear()
+            _profiled.clear()
             _events_dropped = 0
             _gauge_events.clear()
             _gauge_events_dropped = 0
@@ -311,18 +321,69 @@ def _span_stack() -> list:
     return st
 
 
-def _trace_annotation(name: str):
-    """A `jax.profiler.TraceAnnotation` when jax is ALREADY imported in
+_annotation_cls = None     # jax.profiler.TraceAnnotation, once jax is in
+
+
+def _recording():
+    """`jax.profiler.TraceAnnotation` while a profiler session records in
     this process, else None.  Telemetry never imports jax itself: the
     bench parent that uses it must stay off JAX, so that its worker
     processes can hold the device."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return None
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            cls = _annotation_cls = jax.profiler.TraceAnnotation
+        except AttributeError:      # jax is still importing
+            return None
+    return cls if cls.is_enabled() else None
+
+
+def _note_profiled(name: str, dur: float) -> None:
+    with _lock:
+        s = _profiled.get(name)
+        if s is None:
+            _profiled[name] = [1, dur]
+        else:
+            s[0] += 1
+            s[1] += dur
+
+
+def profiled_spans() -> dict:
+    """{span name: {"count", "total_s"}} of the spans that opened and
+    closed while a profiler session recorded, since the process started
+    (or the last `reset(full=True)`): the same sections as the trace's
+    `cst.*` host events, counted without reducing the trace."""
+    with _lock:
+        return {k: {"count": c, "total_s": t}
+                for k, (c, t) in _profiled.items()}
+
+
+class _ProfiledSpan:
+    """The profiler half alone: a `cst.<name>` annotation, and the span's
+    duration added to `profiled_spans()` if the session still records
+    when it closes."""
+
+    __slots__ = ("name", "ann", "t0")
+
+    def __init__(self, name: str, cls):
+        self.name = name
+        self.ann = cls("cst." + name)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter() - self.t0
+        self.ann.__exit__(exc_type, exc, tb)
+        if _recording() is not None:
+            _note_profiled(self.name, dur)
+        return False
 
 
 class _Span:
@@ -337,22 +398,17 @@ class _Span:
         stack = _span_stack()
         self.parent = stack[-1] if stack else None
         stack.append(self.name)
-        self.ann = _trace_annotation(self.name)
-        if self.ann is not None:
-            try:
-                self.ann.__enter__()
-            except Exception:
-                self.ann = None
+        cls = _recording()
+        if cls is not None:
+            self.ann = _ProfiledSpan(self.name, cls)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
         if self.ann is not None:
-            try:
-                self.ann.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
+            self.ann.__exit__(exc_type, exc, tb)
         stack = _span_stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -394,10 +450,14 @@ def span(name: str, **attrs):
         with telemetry.span("bls.batch_verify", lanes=128):
             ...
 
-    Disabled mode returns one shared no-op object (no allocation)."""
-    if not _enabled:
+    With the registry off it is the profiler half alone while a profiler
+    session records, and else one shared no-op object (no allocation)."""
+    if _enabled:
+        return _Span(name, attrs)
+    cls = _recording()
+    if cls is None:
         return _NULL_SPAN
-    return _Span(name, attrs)
+    return _ProfiledSpan(name, cls)
 
 
 # --- snapshot ---------------------------------------------------------------

@@ -8,7 +8,8 @@ component-level counterpart of `test_bls_jax.py`'s accept/reject parity:
 - precomputed-line (fixed-G2-argument) pairing vs `ops/bls/pairing.py`;
 - the shared-accumulator invariant: ONE unbatched Fq12 squaring per
   Miller-loop bit in the traced program, independent of batch size;
-- `_bucket` shape-ladder regression (n = 0/1 edges, <= 4 jit shapes).
+- `_bucket` shape-ladder regression (n = 0/1 edges, <= 4 jit shapes);
+- the RLC kernel's program name and its `cst.rlc.*` stage scopes.
 
 All CPU-runnable with small batch buckets (JAX_PLATFORMS=cpu is pinned by
 conftest).  The full hash/pairing programs compile for tens of seconds on
@@ -174,3 +175,19 @@ def test_batch_verify_device_h2c_parity():
     bad[1] = (bad[1][0], bad[1][1], C.g2.mul(C.G2_GEN, 31337))
     assert bb.batch_verify(bad, rng=rng, device_h2c=True) is False
     assert bb.batch_verify(bad, rng=rng, device_h2c=False) is False
+
+
+def test_rlc_kernel_lowering_names_its_stages():
+    """The device-hash RLC kernel at the bottom rung lowers as its own
+    program, `rlc_verify_h2c`, with each stage under a `cst.rlc.*` scope
+    (scopes are metadata: the program is the same)."""
+    B = bb._bucket(1)
+    args = (np.zeros((B, 33), np.int32), np.zeros((B, 33), np.int32),
+            np.zeros((B, 2, 33), np.int32), np.zeros((B, 2, 33), np.int32),
+            np.zeros((B, 8), np.uint32), np.zeros((B, 128), np.int32),
+            np.zeros((B,), bool))
+    text = bb._rlc_kernel_h2c(B).lower(*args).as_text(debug_info=True)
+    assert "module @jit_rlc_verify_h2c" in text
+    for scope in ("cst.rlc.h2c", "cst.rlc.scalar_mul", "cst.rlc.sig_sum",
+                  "cst.rlc.miller_loop", "cst.rlc.final_exp"):
+        assert scope in text, scope

@@ -1,7 +1,8 @@
 """`utils.jaxtools.enable_compile_cache`: where the persistent compile
 cache lives.  The path is part of the cache's key, so it must not depend
 on the host (core count, CPU flags) and must be placeable from outside
-through `JAX_COMPILATION_CACHE_DIR`."""
+through `JAX_COMPILATION_CACHE_DIR`.  And the build ledger: JAX's trace,
+lowering and compile seconds by function, each instant counted once."""
 
 import os
 import subprocess
@@ -71,3 +72,45 @@ def test_setup_failure_is_reported_not_swallowed(restored_cache_config,
         m.setattr(jax.config, "update", refuse)
         jaxtools.enable_compile_cache()
     assert "compile cache not enabled: ValueError" in capsys.readouterr().err
+
+
+def test_build_ledger_names_the_function_it_built():
+    import jax.numpy as jnp
+
+    def ledger_probe_kernel(x):
+        return jnp.sin(x) * 3 + x
+
+    kernel = jaxtools.jit(ledger_probe_kernel)
+    before = jaxtools.build_seconds()
+    kernel(jnp.arange(16.0)).block_until_ready()
+    row = jaxtools.builds()["ledger_probe_kernel"]
+    assert row["count"] == 1
+    assert row["trace_s"] > 0 and row["lower_s"] > 0 and row["compile_s"] > 0
+    after = jaxtools.build_seconds()
+    for phase in ("trace_s", "lower_s", "compile_s"):
+        assert after[phase] > before[phase], phase
+    # a second call builds nothing
+    kernel(jnp.arange(16.0)).block_until_ready()
+    assert jaxtools.builds()["ledger_probe_kernel"] == row
+
+
+@pytest.mark.parametrize("rows, want", [
+    # disjoint builds add up
+    ([("f", "trace", 0.0, 1.0), ("f", "lower", 1.0, 3.0),
+      ("f", "compile", 3.0, 6.0)], (1.0, 2.0, 3.0)),
+    # a function traced inside another's trace counts once
+    ([("outer", "trace", 0.0, 4.0), ("inner", "trace", 1.0, 2.0)],
+     (4.0, 0.0, 0.0)),
+    # a constant compiled inside a trace is compile time, not trace time
+    ([("outer", "trace", 0.0, 4.0), ("iota", "compile", 1.0, 2.5)],
+     (2.5, 0.0, 1.5)),
+    # overlapping builds on two threads: the later-started one owns the
+    # overlap
+    ([("a", "lower", 0.0, 3.0), ("b", "compile", 2.0, 5.0)],
+     (0.0, 2.0, 3.0)),
+])
+def test_build_seconds_counts_each_instant_once(monkeypatch, rows, want):
+    monkeypatch.setattr(jaxtools, "_builds", rows)
+    got = jaxtools.build_seconds()
+    assert (got["trace_s"], got["lower_s"], got["compile_s"]) == \
+        pytest.approx(want)

@@ -350,3 +350,102 @@ def test_span_seconds_point_read():
     telemetry.add_event("spec.build", 1.25)
     telemetry.add_event("spec.build", 0.25)
     assert telemetry.span_seconds("spec.build") == 1.5
+
+
+# --- the profiler half ------------------------------------------------------
+
+
+def _wire_statement():
+    from consensus_specs_tpu.ops.bls import ciphersuite
+
+    msg = b"\x07" * 32
+    return ciphersuite.SkToPk(5), msg, ciphersuite.Sign(5, msg)
+
+
+def _cst_host_events(logdir) -> list:
+    """(name, thread line, start_ns, end_ns) of the `cst.*` host events of
+    the one trace under `logdir`."""
+    from jax.profiler import ProfileData
+
+    (path,) = list(logdir.rglob("*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("cst."):
+                    out.append((ev.name, line.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_profiler_captures_parse_phases_with_registry_off(tmp_path):
+    """A profiler session sees the wire parse and its four phases, nested,
+    on the profiler's clock, with the registry off; the program's own
+    count of those spans agrees with the trace."""
+    import jax
+
+    from consensus_specs_tpu.serve import ServeExecutor
+
+    pk, msg, sig = _wire_statement()
+    ex = ServeExecutor()
+    with jax.profiler.trace(str(tmp_path)):
+        ex.submit_fast_aggregate_verify([pk], msg, sig)
+    events = _cst_host_events(tmp_path)
+    (parse,) = [e for e in events if e[0] == "cst.serve.parse"]
+    phases = {e[0]: e for e in events if e[0].startswith("cst.bls.")}
+    assert set(phases) == {"cst.bls.decompress_g1", "cst.bls.subgroup_g1",
+                           "cst.bls.decompress_g2", "cst.bls.subgroup_g2"}
+    for name, line, start, end in phases.values():
+        assert line == parse[1], name
+        assert parse[2] <= start <= end <= parse[3], name
+    counted = telemetry.profiled_spans()
+    assert {k: v["count"] for k, v in counted.items()} == {
+        "serve.parse": 1, "bls.decompress_g1": 1, "bls.subgroup_g1": 1,
+        "bls.decompress_g2": 1, "bls.subgroup_g2": 1}
+    for name, line, start, end in events:
+        total_s = counted[name[len("cst."):]]["total_s"]
+        assert total_s == pytest.approx((end - start) / 1e9, abs=1e-4)
+    # the registry half stayed off
+    assert telemetry.snapshot()["spans"] == {}
+
+
+def test_no_session_and_registry_off_span_is_the_noop():
+    import jax  # noqa: F401  the profiler's state is read once jax is in
+
+    assert telemetry.span("serve.parse") is core._NULL_SPAN
+    with telemetry.span("serve.parse"):
+        pass
+    assert telemetry.profiled_spans() == {}
+
+
+def test_registry_span_also_reaches_the_profiler(tmp_path):
+    """With the registry on, a span is recorded there as before (name,
+    parent) and, inside a profiler session, annotated as `cst.<name>`."""
+    import jax
+
+    telemetry.configure(enabled=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.span("bls.batch_verify", tasks=2):
+            with telemetry.span("bls.prepare"):
+                pass
+    events, _ = core._events_copy()
+    by_name = {e["name"]: e for e in events}
+    assert by_name["bls.prepare"]["args"]["parent"] == "bls.batch_verify"
+    assert {e[0] for e in _cst_host_events(tmp_path)} == {
+        "cst.bls.batch_verify", "cst.bls.prepare"}
+    assert set(telemetry.profiled_spans()) == {"bls.batch_verify",
+                                               "bls.prepare"}
+
+
+def test_span_left_open_when_the_session_stops_is_not_counted(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    span = telemetry.span("serve.parse")
+    with span:
+        jax.profiler.stop_trace()
+    assert telemetry.profiled_spans() == {}
+    # and with the session gone, spans are the no-op again
+    assert telemetry.span("serve.parse") is core._NULL_SPAN
