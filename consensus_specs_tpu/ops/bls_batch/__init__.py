@@ -21,6 +21,10 @@ Public surface:
                                    is device-resident end to end.
   g1_multi_exp_device(pts, ks)     G1 multiscalar multiplication via a
                                    windowed bucketed (Pippenger) kernel.
+  registry.PubkeyRegistry          the node's validated keys on device;
+                                   its committee aggregation program
+                                   feeds batch_verify's pubkey side
+                                   (`pubkeys=`) from aggregation bits.
 
 Every entry point also has an `_async` variant returning a
 `serve.futures.DeviceFuture` (the deferred-result contract): host prep +
@@ -597,7 +601,8 @@ def g1_multi_exp_sharded(points, scalars, n_devices: int | None = None,
         device_ids=device_ids).result()
 
 
-def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False):
+def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False,
+                        device_pubkeys: bool = False):
     """Host-side prep shared by the single-device and sharded RLC paths:
     drop trivial pairs, hash messages (host) or pack them as uint32 words
     (device h2c), build limb arrays padded to `lanes` (or the bucket
@@ -606,19 +611,28 @@ def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False):
     Returns (arrays, n_live) with arrays None when a degenerate path
     already decided the answer (n_live then carries the bool).  With
     device_h2c the h_x/h_y slots of the array tuple are replaced by one
-    (B, 8) big-endian message-word matrix."""
+    (B, 8) big-endian message-word matrix.  With device_pubkeys the tasks'
+    pubkey slots are None and so are the pk_x/pk_y slots of the arrays:
+    the caller supplies them from the device; no lane is dropped, and a
+    signature at infinity decides the batch False (only an aggregate at
+    infinity could match it, and KeyValidate refuses that one; the
+    recheck gives each statement its own verdict)."""
     live = []
     for pk, msg, sig in tasks:
-        if _pycurve.g1.is_inf(pk) and _pycurve.g2.is_inf(sig):
+        if not device_pubkeys and _pycurve.g1.is_inf(pk) \
+                and _pycurve.g2.is_inf(sig):
             continue          # 1 == 1 trivially; mirrors oracle skip
         live.append((pk, bytes(msg), sig))
     if not live:
         return None, True
+    if device_pubkeys and any(_pycurve.g2.is_inf(t[2]) for t in live):
+        return None, False
 
     # infinity on only one side cannot go through the affine kernels —
     # fall back to per-task device checks (rare, adversarial-only)
-    if any(_pycurve.g1.is_inf(pk) or _pycurve.g2.is_inf(sig)
-           for pk, _, sig in live):
+    if not device_pubkeys and any(
+            _pycurve.g1.is_inf(pk) or _pycurve.g2.is_inf(sig)
+            for pk, _, sig in live):
         ok = all(
             pairing_check_device([(pk, hash_to_g2(msg, DST_G2)),
                                   (_pycurve.g1.neg(_pycurve.G1_GEN), s)])
@@ -627,7 +641,9 @@ def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False):
 
     B = _bucket(len(live)) if lanes is None else lanes
     assert B >= len(live)
-    pk_x, pk_y = cj.g1_affine_to_limbs([t[0] for t in live])
+    pk_x = pk_y = None
+    if not device_pubkeys:
+        pk_x, pk_y = cj.g1_affine_to_limbs([t[0] for t in live])
     if device_h2c:
         from . import h2c_jax as h2c
         h_arrays = (h2c.msgs_to_words([t[1] for t in live]),)
@@ -642,7 +658,8 @@ def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False):
     pad = B - len(live)
     if pad:
         def _p(a):
-            return np.concatenate([a, np.repeat(a[:1], pad, 0)])
+            return None if a is None else np.concatenate(
+                [a, np.repeat(a[:1], pad, 0)])
         pk_x, pk_y = _p(pk_x), _p(pk_y)
         h_arrays = tuple(_p(a) for a in h_arrays)
         sig_x, sig_y = _p(sig_x), _p(sig_y)
@@ -653,8 +670,15 @@ def _prepare_rlc_inputs(tasks, rand, lanes: int, device_h2c: bool = False):
             len(live))
 
 
+def _no_infinite_key(n: int, host) -> bool:
+    """The verdict of a batch whose keys the device aggregated: the RLC
+    kernel's, and no live statement's aggregate key at infinity."""
+    ok, inf = host
+    return bool(ok) and not bool(np.any(inf[:n]))
+
+
 def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
-                       block: bool = True) -> DeviceFuture:
+                       block: bool = True, pubkeys=None) -> DeviceFuture:
     """tasks: [(g1_pubkey_jacobian, message_bytes, g2_sig_jacobian)].
 
     Verifies all FastAggregateVerify-style statements
@@ -666,7 +690,14 @@ def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
 
     With device_h2c (the default for 32-byte message roots; opt out with
     CST_BLS_DEVICE_H2C=0) the message hashing runs on device too, so the
-    host only parses points and draws coefficients."""
+    host only parses points and draws coefficients.
+
+    With `pubkeys` (a `registry.CommitteeKeys`, one committee selection
+    per task) the tasks' pubkey slots are None: the committee aggregation
+    program sums each statement's keys from the device registry and its
+    output enters the RLC kernel as pk_x, pk_y.  The selection's host prep
+    runs inside `bls.prepare` and its dispatch inside `bls.enqueue`; the
+    verdict is False too where a live aggregate is infinity."""
     if not tasks:
         return DeviceFuture.settled(True)
     rand = rng if rng is not None else secrets.SystemRandom()
@@ -679,8 +710,11 @@ def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
                         device_h2c=device_h2c):
         telemetry.count("bls.batch_verify.calls")
         with telemetry.span("bls.prepare"):
-            arrays, n = _prepare_rlc_inputs(tasks, rand, None,
-                                            device_h2c=device_h2c)
+            arrays, n = _prepare_rlc_inputs(
+                tasks, rand, None, device_h2c=device_h2c,
+                device_pubkeys=pubkeys is not None)
+            if arrays is not None and pubkeys is not None:
+                keys_host = pubkeys.prepare(_bucket(n))
         if arrays is None:
             # degenerate path: trivial skip or the per-task host
             # fallback — no statements reached the batched kernel
@@ -698,10 +732,17 @@ def batch_verify_async(tasks, rng=None, device_h2c: bool | None = None,
         kernel = _rlc_kernel_h2c if device_h2c else _rlc_kernel
         name = f"rlc_{'h2c' if device_h2c else 'host_hash'}@{B}"
         with telemetry.span("bls.enqueue"):
-            out = _dispatch(name, kernel(B),
-                            tuple(jnp.asarray(a) for a in arrays),
-                            block=block)
-    return bool_future(out)
+            if pubkeys is None:
+                args = tuple(jnp.asarray(a) for a in arrays)
+            else:
+                pk_x, pk_y, pk_inf = pubkeys.enqueue(keys_host, block=block)
+                args = (pk_x, pk_y) + tuple(jnp.asarray(a)
+                                            for a in arrays[2:])
+            out = _dispatch(name, kernel(B), args, block=block)
+    if pubkeys is None:
+        return bool_future(out)
+    return value_future((out, pk_inf),
+                        convert=functools.partial(_no_infinite_key, n))
 
 
 def batch_verify(tasks, rng=None, device_h2c: bool | None = None) -> bool:

@@ -25,6 +25,13 @@ Request kinds and their device paths:
                per-statement recheck (`pairing_check_device`) so each
                handle gets its own verdict — all-or-nothing is a block
                semantics, not a serving one.
+    committee  FastAggregateVerify of a committee aggregate whose keys
+               the node holds: the executor's `registry`
+               (`bls_batch.registry.PubkeyRegistry`) names the members,
+               the aggregation bits select them.  Batched like verify;
+               each batch dispatches the committee aggregation program,
+               then the RLC program on its output.  A false batch reads
+               its aggregate keys back for the per-statement recheck.
     pairing    one pairing-product check (`pairing_check_device_async`)
     msm        one G1 MSM (`g1_multi_exp_device_async`)
     sha256     one Merkle-root reduction (`merkleize_words_jax_async`)
@@ -109,7 +116,9 @@ from ..telemetry import flightrec, occupancy, reqtrace
 from .futures import DeviceFuture, FutureTimeout
 
 KINDS = ("verify", "pairing", "msm", "sha256", "fr", "proof", "das",
-         "recover", "fc_atts", "head")
+         "recover", "fc_atts", "head", "committee")
+# kinds whose requests share one device batch, up to max_batch each
+_BATCHED_KINDS = ("verify", "das", "committee")
 
 # batched-kind dispatchers resolve lazily: importing the executor must
 # not pull jax/numpy-heavy ops modules until the first dispatch
@@ -133,15 +142,16 @@ class _Request:
 
 class _Batch:
     __slots__ = ("kind", "future", "reqs", "t_dispatch", "attempt",
-                 "occ")
+                 "occ", "keys")
 
-    def __init__(self, kind, future, reqs, attempt=1, occ=None):
+    def __init__(self, kind, future, reqs, attempt=1, occ=None, keys=None):
         self.kind = kind
         self.future = future
         self.reqs = reqs
         self.t_dispatch = time.perf_counter()
         self.attempt = attempt
         self.occ = occ          # occupancy.BatchSpan (None when off)
+        self.keys = keys        # registry.CommitteeKeys (committee kind)
 
 
 def _depth_bucket(n: int) -> str:
@@ -188,6 +198,19 @@ def _oracle_verify(task) -> bool:
     return ok
 
 
+def _oracle_committee(payload) -> bool:
+    """The committee statement on the oracle: the pure-Python sum of the
+    registry's host mirror, then FastAggregateVerify of the aggregate."""
+    from ..ops.bls.ciphersuite import _pairing_check, fast_aggregate_pairs
+    from ..ops.bls.curve import g1
+
+    registry, committee_id, bits, msg, sig = payload
+    agg = registry.host_aggregate(committee_id, bits)
+    if g1.is_inf(agg):
+        return False            # KeyValidate of the aggregate
+    return _pairing_check(fast_aggregate_pairs((agg, msg, sig)))
+
+
 def _oracle_barycentric(poly_ints, roots_brp_ints, z_int) -> int:
     """The closed-form host evaluation `fr_batch` mirrors: f(z) =
     (z^W - 1)/W * sum_i f_i * w_i / (z - w_i) mod r, with the in-domain
@@ -214,6 +237,8 @@ def _oracle_compute(kind: str, payload):
     for kinds without an oracle (`proof`)."""
     if kind == "verify":
         return _oracle_verify(payload)
+    if kind == "committee":
+        return _oracle_committee(payload)
     if kind == "pairing":
         from ..ops.bls.ciphersuite import _pairing_check
 
@@ -257,7 +282,7 @@ def _oracle_compute(kind: str, payload):
 
 
 ORACLE_KINDS = frozenset({"verify", "pairing", "msm", "sha256", "fr",
-                          "das", "recover", "fc_atts", "head"})
+                          "das", "recover", "fc_atts", "head", "committee"})
 
 
 class ServeExecutor:
@@ -266,14 +291,18 @@ class ServeExecutor:
     `depth` is the number of in-flight batches the pipeline holds
     before settling the oldest.  `retry`/`breakers`/`deadline_ms` arm
     the resilience policies (all off by default; `deadline_ms` falls
-    back to the CST_SERVE_DEADLINE_MS knob)."""
+    back to the CST_SERVE_DEADLINE_MS knob).  `registry` (a
+    `bls_batch.registry.PubkeyRegistry`) is the key cache committee
+    submits name their keys in."""
 
     def __init__(self, max_batch: int = 512, depth: int = 2,
                  retry=None, breakers=None,
-                 deadline_ms: float | None = None, mesh=None):
+                 deadline_ms: float | None = None, mesh=None,
+                 registry=None):
         assert max_batch >= 1 and depth >= 1
         self.max_batch = max_batch
         self.depth = depth
+        self.registry = registry
         self.retry = retry
         self.breakers = breakers
         # a resilience.mesh.MeshVerifier: verify batches dispatch over
@@ -299,6 +328,7 @@ class ServeExecutor:
         self._retries = 0
         self._fallbacks = 0
         self._shed = 0
+        self._keys_aggregated = 0
         self._poisoned_batches = 0
         self._poison_dumped = False
         self._queue_hist: dict[str, int] = {}
@@ -350,6 +380,37 @@ class ServeExecutor:
             telemetry.count("serve.rejected_eager")
             return DeviceFuture.settled(False)
         return self.submit_verify_task(task)
+
+    def submit_committee_aggregate_verify(self, slot: int,
+                                          committee_index: int,
+                                          aggregation_bits, message,
+                                          signature) -> DeviceFuture:
+        """FastAggregateVerify of one committee aggregate against the
+        executor's registry: `aggregation_bits` (SSZ `Bitlist` bytes)
+        select the members of committee `committee_index` at `slot`.
+        Only the signature is parsed (decompression and the G2 subgroup
+        check); the keys are the registry's.  An unknown committee, bits
+        of another length than the committee's, no bit set, or a bad
+        signature settle False at once."""
+        from ..ops.bls.ciphersuite import _sig_to_point
+        from ..ops.bls.curve import g2
+        from ..ops.bls_batch.registry import decode_bitlist
+
+        if self.registry is None:
+            raise ValueError("committee submits need a registry")
+        with telemetry.span("serve.parse"):
+            committee_id = self.registry.committee_id(slot, committee_index)
+            bits = decode_bitlist(aggregation_bits, self.registry.size)
+            try:
+                sig = _sig_to_point(bytes(signature))
+            except ValueError:
+                sig = None
+        if committee_id is None or bits is None or not bits.any() \
+                or sig is None or g2.is_inf(sig):
+            telemetry.count("serve.rejected_eager")
+            return DeviceFuture.settled(False)
+        return self._submit("committee", (self.registry, committee_id, bits,
+                                          bytes(message), sig))
 
     def submit_pairing(self, pairs) -> DeviceFuture:
         """One product-of-pairings check (sync-aggregate shape)."""
@@ -507,6 +568,7 @@ class ServeExecutor:
             if faults.active():
                 faults.maybe_inject("serve_pump", kind)
             bb = _ops_bls_batch()
+            keys = None
             # block=False: the pipelined-dispatch contract — on
             # instrumented rounds the telemetry seam must not
             # block_until_ready between batches (see bls_batch._dispatch)
@@ -517,6 +579,13 @@ class ServeExecutor:
                 else:
                     fut = bb.batch_verify_async(
                         [r.payload for r in reqs], block=False)
+            elif kind == "committee":
+                registry = reqs[0].payload[0]
+                keys = registry.select([r.payload[1] for r in reqs],
+                                       [r.payload[2] for r in reqs])
+                fut = bb.batch_verify_async(
+                    [(None, r.payload[3], r.payload[4]) for r in reqs],
+                    block=False, pubkeys=keys)
             elif kind == "pairing":
                 fut = bb.pairing_check_device_async(reqs[0].payload,
                                                     block=False)
@@ -580,7 +649,7 @@ class ServeExecutor:
         if occ is not None:
             occ.mark_dispatch()
         self._inflight.append(_Batch(kind, fut, reqs, attempt=attempt,
-                                     occ=occ))
+                                     occ=occ, keys=keys))
         self._dispatched_batches += 1
         telemetry.count(f"serve.dispatch.{kind}")
         self._note_inflight()
@@ -599,7 +668,7 @@ class ServeExecutor:
             reqs = by_kind.get(kind)
             if not reqs:
                 continue
-            if kind in ("verify", "das"):
+            if kind in _BATCHED_KINDS:
                 # batched kinds: up to max_batch requests per device
                 # dispatch (das folds the samples' cell statements into
                 # one RLC batch)
@@ -651,6 +720,19 @@ class ServeExecutor:
         return _ops_bls_batch().pairing_check_device(
             fast_aggregate_pairs(task))
 
+    def _recheck_committee(self, batch: _Batch) -> list:
+        """Per-statement verdicts for a failed committee batch, from its
+        aggregate keys read back from the device."""
+        points = batch.keys.points()
+        return [pk is not None
+                and self._verify_single((pk, r.payload[3], r.payload[4]))
+                for pk, r in zip(points, batch.reqs)]
+
+    def _note_keys(self, kind: str, reqs: list[_Request]) -> None:
+        if kind == "committee":
+            self._keys_aggregated += sum(int(r.payload[2].sum())
+                                         for r in reqs)
+
     def _serve_fallback(self, kind: str, reqs: list[_Request]) -> None:
         """Degraded mode: answer on the pure-Python oracle (correct but
         slow) while the breaker holds the device path open.  Each
@@ -680,6 +762,7 @@ class ServeExecutor:
                                      final_component="detour")
                 now_latencies.append(req.t_enqueue)
                 self._settled += 1
+                self._note_keys(kind, [req])
             now = time.perf_counter()
             self.latencies_s.extend(now - t for t in now_latencies)
             self._fallbacks += len(reqs)
@@ -752,14 +835,17 @@ class ServeExecutor:
                     batch.occ.mark_answer()
                 for ctx in ctxs:
                     ctx.mark_device_done()
-                if batch.kind == "verify" and len(batch.reqs) > 1:
+                if batch.kind in ("verify", "committee") \
+                        and len(batch.reqs) > 1:
                     if out:
                         results = [True] * len(batch.reqs)
                     else:
                         self._rechecks += 1
                         telemetry.count("serve.batch_recheck")
-                        results = [self._verify_single(r.payload)
-                                   for r in batch.reqs]
+                        results = (self._recheck_committee(batch)
+                                   if batch.kind == "committee" else
+                                   [self._verify_single(r.payload)
+                                    for r in batch.reqs])
                         # the per-statement recheck wall is a detour,
                         # and the outcome label upgrades to "recheck"
                         for ctx in ctxs:
@@ -807,6 +893,7 @@ class ServeExecutor:
                     req.ctx.complete()
                 self.latencies_s.append(now - req.t_enqueue)
             self._settled += len(batch.reqs)
+            self._note_keys(batch.kind, batch.reqs)
             telemetry.count("serve.settled", len(batch.reqs))
             if batch.occ is not None:
                 batch.occ.mark_settled()
@@ -896,6 +983,7 @@ class ServeExecutor:
             "retries": self._retries,
             "fallbacks": self._fallbacks,
             "shed": self._shed,
+            "keys_aggregated": self._keys_aggregated,
             "queue_depth": {"max": self._queue_max,
                             "hist": dict(self._queue_hist)},
             "inflight_max": self._inflight_max,

@@ -89,6 +89,19 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
         assert out.shape == shapes[0][0] and out.dtype == np.int32
 
 
+def test_registry_fill_compiles_for_v5e(one_chip):
+    """The pubkey registry's fill program at its real chunk of keys."""
+    from consensus_specs_tpu.ops.bls_batch import registry
+
+    chunk = registry.FILL_CHUNK
+    compiled = _compile_fits(registry._fill_kernel(chunk),
+                             [((chunk, 2, registry.COORD_BYTES), jnp.uint8)],
+                             one_chip)
+    x, y = compiled.out_info
+    assert x.shape == y.shape == (chunk, fq.N_LIMBS)
+    assert x.dtype == np.int32
+
+
 @pytest.mark.parametrize("collective", ["psum", "psum_scatter"])
 def test_u64_collectives_compile_for_a_v5e_mesh(mesh4, collective):
     """The sharded sweep's uint64 totals and proposer-reward scatter:
