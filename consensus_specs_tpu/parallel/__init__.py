@@ -45,7 +45,7 @@ from .merkle import (  # noqa: F401
     ValidatorLeaves,
     balances_list_root,
     pack_u64_chunks,
-    u64_leaf_words,
+    u64_leaf_planes,
     validator_records_root,
     validator_registry_root,
 )

@@ -4,8 +4,9 @@ The reference amortizes `hash_tree_root(state)` with remerkleable's cached
 pointer-tree (`eth2spec/utils/ssz/ssz_impl.py:25`).  The TPU redesign keeps
 the big lists (balances, validators) as flat arrays and re-hashes them as a
 batched tree reduction on device — at 1M validators the whole balances tree
-is ~19 SHA-256 levels of perfectly regular (N, 16)-word batches, exactly the
-shape `ops.sha256_jax` wants.
+is ~19 SHA-256 levels of perfectly regular batches of 64-byte blocks, held
+as word planes (word j of every node in row j) for `ops.sha256_jax`'s
+kernel.
 
 Sharded form: each device reduces its local contiguous sub-tree, the (tiny)
 per-device roots are `all_gather`ed over the mesh axis and folded on every
@@ -21,19 +22,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from .. import telemetry
-from ..ops.sha256_jax import hash_pairs, sha256_64B_words
-from ..ops.sha256_np import ZERO_HASH_WORDS
+from ..ops.sha256_jax import reduce_planes, sha256_64B_planes, zero_ladder
 
 # uint64 packing needs x64; entry points enable it (see parallel.require_x64)
-
-# plain numpy at module level (jnp closes over it at trace time):
-# import-time jnp arrays leak tracers if this module's first import
-# happens inside a jit trace — the device-const-at-import rule
-_ZEROS = np.stack(ZERO_HASH_WORDS[:64])  # (64, 8) uint32
 
 
 def _bswap32(x):
@@ -56,13 +50,13 @@ def pack_u64_chunks(values):
                       lo[:, 2], hi[:, 2], lo[:, 3], hi[:, 3]], axis=-1)
 
 
-def u64_leaf_words(values):
-    """(N,) uint64 -> (N, 8) chunk words: each value alone in a 32B chunk
-    (an SSZ uint64 field leaf)."""
+def u64_leaf_planes(values):
+    """(N,) uint64 -> (8, N) word planes of SSZ uint64 field leaves: each
+    value alone in a 32-byte chunk, so two planes (lo, hi) and six zero."""
     lo = _bswap32(values & jnp.uint64(0xFFFFFFFF))
     hi = _bswap32(values >> jnp.uint64(32))
     z = jnp.zeros_like(lo)
-    return jnp.stack([lo, hi, z, z, z, z, z, z], axis=-1)
+    return jnp.stack([lo, hi, z, z, z, z, z, z])
 
 
 def subtree_root(words, depth: int):
@@ -72,14 +66,8 @@ def subtree_root(words, depth: int):
     n = words.shape[0]
     assert n & (n - 1) == 0 and n >= 1
     data_depth = n.bit_length() - 1
-    level = words
-    for _ in range(data_depth):
-        level = hash_pairs(level)
-    root = level[0]
-    for d in range(data_depth, depth):
-        blk = jnp.concatenate([root, _ZEROS[d]])
-        root = sha256_64B_words(blk[None, :])[0]
-    return root
+    root = reduce_planes(words.T, data_depth)[:, 0]
+    return zero_ladder(root, data_depth, depth)
 
 
 def mix_in_length(root_words, length):
@@ -89,7 +77,7 @@ def mix_in_length(root_words, length):
     z = jnp.zeros((), dtype=jnp.uint32)
     tail = jnp.stack([lo, hi, z, z, z, z, z, z])
     blk = jnp.concatenate([root_words, tail])
-    return sha256_64B_words(blk[None, :])[0]
+    return sha256_64B_planes(blk[:, None])[:, 0]
 
 
 def balances_list_root(balances, length, limit_depth: int = 38,
@@ -128,14 +116,8 @@ def _sharded_list_root(local_chunks, limit_depth: int, axis_name: str):
     assert n_dev & (n_dev - 1) == 0, (
         f"sharded list root needs a power-of-two mesh, got {n_dev} devices")
     shard_depth = (n_dev - 1).bit_length()
-    level = roots
-    for _ in range(shard_depth):
-        level = hash_pairs(level)
-    root = level[0]
-    for d in range(local_depth + shard_depth, limit_depth):
-        blk = jnp.concatenate([root, _ZEROS[d]])
-        root = sha256_64B_words(blk[None, :])[0]
-    return root
+    root = reduce_planes(roots.T, shard_depth)[:, 0]
+    return zero_ladder(root, local_depth + shard_depth, limit_depth)
 
 
 class ValidatorLeaves:
@@ -158,24 +140,27 @@ def validator_records_root(leaves: ValidatorLeaves, effective_balance,
                            slashed, activation_eligibility_epoch,
                            activation_epoch, exit_epoch, withdrawable_epoch):
     """(N,) arrays -> (N, 8) root words of each Validator container (a full
-    depth-3 reduction over the 8 field leaves, batched over validators)."""
-    with telemetry.span("parallel.validator_records_root.trace",
-                        n=int(effective_balance.shape[0])), \
+    depth-3 reduction over the 8 field leaves, batched over validators).
+    Each leaf is 8 word planes, so a record's siblings pair by stacking
+    planes: three kernel calls of 4N, 2N and N hashes."""
+    n = int(effective_balance.shape[0])
+    with telemetry.span("parallel.validator_records_root.trace", n=n), \
             jax.named_scope("cst.validator_records_root"):
-        f = [leaves.pubkey_root,
-             leaves.credentials,
-             u64_leaf_words(effective_balance),
-             u64_leaf_words(slashed.astype(jnp.uint64)),
-             u64_leaf_words(activation_eligibility_epoch),
-             u64_leaf_words(activation_epoch),
-             u64_leaf_words(exit_epoch),
-             u64_leaf_words(withdrawable_epoch)]
-        level = jnp.stack(f, axis=1)        # (N, 8 leaves, 8 words)
-        for _ in range(3):
-            half = level.shape[1] // 2
-            level = sha256_64B_words(
-                level.reshape(level.shape[0], half, 16))
-        return level[:, 0, :]
+        f = [leaves.pubkey_root.T,
+             leaves.credentials.T,
+             u64_leaf_planes(effective_balance),
+             u64_leaf_planes(slashed.astype(jnp.uint64)),
+             u64_leaf_planes(activation_eligibility_epoch),
+             u64_leaf_planes(activation_epoch),
+             u64_leaf_planes(exit_epoch),
+             u64_leaf_planes(withdrawable_epoch)]
+        level = jnp.stack(f, axis=1)        # (8 words, 8 leaves, N)
+        for half in (4, 2, 1):
+            # leaf 2p + side of each record -> block p: (16, half * N)
+            blocks = level.reshape(8, half, 2, n).transpose(2, 0, 1, 3)
+            level = sha256_64B_planes(blocks.reshape(16, half * n))
+            level = level.reshape(8, half, n)
+        return level[:, 0, :].T
 
 
 def validator_registry_root(record_roots, length, limit_depth: int = 40,

@@ -89,6 +89,46 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
         assert out.shape == shapes[0][0] and out.dtype == np.int32
 
 
+def test_sha256_kernel_compiles_for_v5e_at_the_records_shape(one_chip):
+    """The 64-byte SHA-256 kernel at the records root's first level for
+    2**20 validators: 4 * 2**20 hashes as (16, 32768, 128) word planes,
+    one TPU custom call and no loop left to XLA."""
+    rows = 4 * (1 << 20) // 128
+    compiled = _compile_fits(sha256_jax._hash64_tiles,
+                             [((16, rows, 128), jnp.uint32)], one_chip)
+    assert compiled.out_info.shape == (8, rows, 128)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" not in text
+
+
+def test_records_root_is_three_kernel_calls_on_v5e(one_chip):
+    """`validator_records_root` at 2**20 validators: one kernel call per
+    level, no `while` loop carrying the rounds through HBM, and each call
+    named under `cst.validator_records_root`, the scope `merkle_ms`
+    reads."""
+    from consensus_specs_tpu.parallel import merkle
+
+    n = 1 << 20
+
+    def records(pubkey_root, credentials, *fields):
+        return merkle.validator_records_root(
+            merkle.ValidatorLeaves(pubkey_root, credentials), *fields)
+
+    shapes = ([((n, 8), jnp.uint32)] * 2 + [((n,), jnp.uint64),
+                                             ((n,), jnp.bool_)]
+              + [((n,), jnp.uint64)] * 4)
+    compiled = _compile_fits(records, shapes, one_chip)
+    assert compiled.out_info.shape == (n, 8)
+    text = compiled.as_text()
+    assert " while(" not in text
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3
+    for line in kernels:
+        assert "/cst.validator_records_root/" in line, line
+
+
 def test_registry_fill_compiles_for_v5e(one_chip):
     """The pubkey registry's fill program at its real chunk of keys."""
     from consensus_specs_tpu.ops.bls_batch import registry
