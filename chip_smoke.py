@@ -45,7 +45,7 @@ REGISTRY_LIMIT = 1 << 40
 
 
 def setup_jax():
-    """x64 on and the persistent compile cache, as the bench workers do."""
+    """x64 on and the persistent compile cache."""
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -144,10 +144,10 @@ def _epoch_step(params):
 
 
 def check_sweep_against_spec(n_validators: int) -> None:
-    """A real mainnet genesis state with attestations (as bench.py's
-    baseline builds it, plus slashed validators) through the state bridge
-    and the device sweep must leave balances and effective balances bit
-    for bit where the pure-Python spec leaves them."""
+    """A real mainnet genesis state with attestations (plus slashed
+    validators) through the state bridge and the device sweep must leave
+    balances and effective balances bit for bit where the pure-Python
+    spec leaves them."""
     from consensus_specs_tpu.models.builder import build_spec
     from consensus_specs_tpu.parallel import (
         EpochParams, RegistryArrays, make_epoch_step, pad_pow2,
@@ -254,6 +254,17 @@ def epoch_phase(n: int = EPOCH_VALIDATORS, steps: int = EPOCH_STEPS,
 # --- phase: serve -----------------------------------------------------------
 
 
+def _build_statements(n: int, keys_per_statement: int, seed_base: int):
+    """n valid FastAggregateVerify statements as wire bytes (pubkey,
+    message, signature), from the serve loadgen's statement pool."""
+    from consensus_specs_tpu.ops.bls import ciphersuite as cs
+    from consensus_specs_tpu.serve.loadgen import build_statement_pool
+
+    return [(cs.g1_to_bytes(pk), msg, cs.g2_to_bytes(sig))
+            for pk, msg, sig in build_statement_pool(
+                n, keys_per_statement, seed_base=seed_base)]
+
+
 def _tamper(raw, i: int):
     """Request i with the next statement's (valid, wrong) signature."""
     pk, msg, _ = raw[i]
@@ -268,14 +279,13 @@ def serve_phase(n_attestations: int = SERVE_ATTESTATIONS,
     `n_attestations` valid aggregate attestations, then `n_tampered`
     single tampered requests; every answer must equal the pure-Python
     oracle's (the `ops.bls` py backend's ciphersuite)."""
-    from bench_bls import _build_tasks
     from consensus_specs_tpu.ops.bls import ciphersuite
     from consensus_specs_tpu.serve import ServeExecutor
 
     oracle = ciphersuite.FastAggregateVerify
     assert n_attestations >= 2 and n_tampered >= 1, (n_attestations,
                                                      n_tampered)
-    _, raw = _build_tasks(n_attestations, committee, seed_base=1000)
+    raw = _build_statements(n_attestations, committee, seed_base=1000)
     tampered = [_tamper(raw, i) for i in range(n_tampered)]
     want_valid = [oracle([pk], msg, sig) for pk, msg, sig in raw]
     want_tampered = [oracle([pk], msg, sig) for pk, msg, sig in tampered]

@@ -27,9 +27,8 @@ bit-identical verdicts, just slow); while the breaker is OPEN the
 settle skips the device entirely, and the half-open probe re-closes it
 once the device answers again.  Degraded settles are counted
 (`flagship.degraded_steps` / `degraded_steps()`), surfaced as the
-chaos round's `"flagship"` block so benchwatch can see a degraded
-round.  Unarmed (the default) the settle path is one None check —
-block-import semantics are unchanged.
+chaos round's `"flagship"` block.  Unarmed (the default) the settle
+path is one None check — block-import semantics are unchanged.
 
 Reference seam being replaced: `eth2spec/utils/bls.py:141-296`'s native
 milagro calls inside `state_transition` (specs/phase0/beacon-chain.md

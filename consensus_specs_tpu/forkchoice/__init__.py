@@ -20,10 +20,6 @@ reductions.
 Serving: `ServeExecutor.submit_attestation_batch` (queued batches fold
 into ONE device dispatch per pump) and `submit_head_request` (the
 `head` request kind); loadgen drives them at `CST_FC_ATTS_PER_SLOT`.
-Bench: `bench.py --worker forkchoice` sweeps `CST_FC_MATRIX`, emitting
-`forkchoice::*` benchwatch records gated by the `fc-speedup` /
-`fc-head-throughput` threshold rows (`make fc-smoke` pins the CPU
-contract).
 """
 
 from .kernels import (

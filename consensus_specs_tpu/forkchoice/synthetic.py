@@ -1,5 +1,5 @@
-"""Synthetic fork-choice workloads — the ONE builder the bench worker
-and the serve loadgen share.
+"""Synthetic fork-choice workloads — one generator for the serve
+loadgen and the device-vs-oracle parity tests.
 
 Both drive the same shape of traffic (a seeded random block tree, an
 all-active 32 ETH validator set, and an attestation stream whose target

@@ -312,9 +312,8 @@ _SKIP_DIRS = {"__pycache__", ".git", ".jax_cache", ".pytest_cache",
 
 def lint_env_knobs(repo=None) -> list[str]:
     """Every `CST_*` env read in the tree needs a row in README.md's
-    knob table, and every row needs a surviving read.  Benchwatch knobs
-    (`CST_BENCHWATCH_*`) additionally need a mention in the README's
-    "Benchwatch" section, serving knobs (`CST_SERVE_*`) in the
+    knob table, and every row needs a surviving read.  Serving knobs
+    (`CST_SERVE_*`) additionally need a mention in the README's
     "Serving" section, incremental-merkleization knobs
     (`CST_MERKLE_*`) in the "Incremental merkleization" section,
     monitoring knobs (`CST_METRICS_*`, `CST_SLO_*`,
@@ -340,9 +339,7 @@ def lint_env_knobs(repo=None) -> list[str]:
                       re.M | re.S)
         return m.group(1) if m else ""
 
-    sectioned_prefixes = (("CST_BENCHWATCH_", "Benchwatch",
-                           section("Benchwatch")),
-                          ("CST_SERVE_", "Serving", section("Serving")),
+    sectioned_prefixes = (("CST_SERVE_", "Serving", section("Serving")),
                           ("CST_MERKLE_", "Incremental merkleization",
                            section("Incremental merkleization")),
                           ("CST_METRICS_", "Monitoring",
@@ -391,7 +388,7 @@ def lint_env_knobs(repo=None) -> list[str]:
             f"README.md: env knob '{name}' documented but never read "
             f"in the tree (stale table row?)")
     for name in sorted(set(used)):
-        # a mention may carry an example value: `CST_BENCHWATCH_STRICT=1`
+        # a mention may carry an example value: `CST_SERVE_CHAOS=1`
         for prefix, title, text in sectioned_prefixes:
             if name.startswith(prefix) and not re.search(
                     rf"`{name}(?:=[^`]*)?`", text):

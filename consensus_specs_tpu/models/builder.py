@@ -337,9 +337,6 @@ def build_spec(fork: str, preset_name: str) -> Spec:
     key = (fork, preset_name)
     if key in _SPEC_CACHE:
         return _SPEC_CACHE[key]
-    # cache misses only: the cumulative `spec.build` span is what the
-    # per-test phase attribution (tests/conftest.py -> benchwatch
-    # tier-1 table) charges to the spec-build phase
     with telemetry.span("spec.build", fork=fork, preset=preset_name):
         ns = _preamble_namespace()
         ns.update(load_preset(preset_name, fork))

@@ -70,9 +70,10 @@ from .partition import (  # noqa: F401
 def require_x64() -> None:
     """The sweep/merkle kernels carry Gwei balances and epochs as uint64;
     without `jax_enable_x64` JAX silently downcasts them to uint32.  The
-    flag is process-wide, so it is set by *entry points* (bench.py,
-    __graft_entry__, tests/conftest.py) — flipping it at import time here
-    would retroactively change dtypes under any host application."""
+    flag is process-wide, so it is set by *entry points* (benchmark/,
+    chip_smoke.py, __graft_entry__, tests/conftest.py) — flipping it at
+    import time here would retroactively change dtypes under any host
+    application."""
     if not jax.config.jax_enable_x64:
         raise RuntimeError(
             "consensus_specs_tpu.parallel needs uint64: enable x64 first "

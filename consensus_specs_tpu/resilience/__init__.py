@@ -44,9 +44,8 @@ machinery to prove it:
                  `CST_CHECKPOINT_EVERY`).
     chaos.py     the chaos-round harness (`CST_SERVE_CHAOS=1`): mainnet
                  arrival mix under an active fault plan, requiring the
-                 service to return to steady state — emits the
-                 `resilience` benchwatch record kind the `chaos-recovery`
-                 threshold row gates on.
+                 service to return to steady state — returns the
+                 `resilience` block.
 
 Import discipline: `faults`, `policies`, `mesh` and `checkpoint` are
 stdlib-only at import (+ telemetry, itself stdlib-only) so the hot-path

@@ -1,6 +1,6 @@
 """Chaos rounds: the mainnet arrival mix under an active fault plan.
 
-`run_chaos_load` (armed by `CST_SERVE_CHAOS=1` in `bench_serve.py`)
+`run_chaos_load` (armed by `CST_SERVE_CHAOS=1` in `serve.loadgen`)
 drives the serve executor through three phases and requires the service
 to come back:
 
@@ -15,8 +15,8 @@ to come back:
                 wrong answers are counted (and must be zero).
     recovery    the plan is cleared; the run continues until throughput
                 is steady again.  `recovery_latency_s` — fault stop to
-                steady-state re-detection — is the `chaos-recovery`
-                benchwatch threshold row's metric.
+                steady-state re-detection — is the round's recovery
+                figure.
 
 Every submitted request is tracked with its EXPECTED outcome (the pool
 statements are valid → True; sha256/fr expectations precomputed on the
@@ -65,13 +65,11 @@ own sub-block of the `"resilience"` object):
                 statements re-bucket over the surviving devices
                 (`resilience.mesh.MeshVerifier`), an invalid statement
                 still rejects while degraded, and the re-admission
-                probe restores the full mesh — the
-                `mesh-recovery`/`mesh-lost-statements` rows.
+                probe restores the full mesh.
 
 Returns `serve.loadgen.run_load`'s block shape (schema:
 `telemetry.export.validate_serve_block`) plus a `"resilience"`
-sub-object (schema: `validate_resilience_block`) that `bench_serve.py`
-embeds and `telemetry.history` mines into `resilience::*` records.
+sub-object (schema: `validate_resilience_block`).
 """
 
 from __future__ import annotations
@@ -239,11 +237,11 @@ def _checkpoint_segment(n_log2: int = 20, update_chunks: int = 256,
     stream, drop the live layer stack, then race checkpoint restore
     (snapshot load + journal replay, zero full re-hash) against the
     full O(N) rebuild.  Root parity is asserted against both the live
-    pre-kill root and the independent pure-host oracle rebuild.  Feeds
-    the `checkpoint-restore` benchwatch threshold row (speedup =
-    rebuild/restore, best-of-2 each so first-touch I/O noise cancels).
+    pre-kill root and the independent pure-host oracle rebuild.  Reports
+    speedup = rebuild/restore, best-of-2 each so first-touch I/O noise
+    cancels.
 
-    2^20 chunks is the acceptance shape (the merkle bench's): big
+    2^20 chunks is the acceptance shape: big
     enough that the O(N) rebuild dominates restore's fixed I/O +
     root-fetch floor — the CPU smoke measures ~8x there, vs ~4x at
     2^17 where a rebuild is only ~0.7s."""

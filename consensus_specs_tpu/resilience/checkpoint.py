@@ -22,8 +22,7 @@ training stacks make optimizer state durable for elastic restart:
                 device puts only) -> replay the journal's dirty
                 updates (O(journal · log N) hash lanes).  At <=1%
                 journal depth this beats the full O(N) re-merkleize
-                >=5x — the `checkpoint-restore` benchwatch threshold
-                row, measured by the chaos checkpoint segment.
+                >=5x, measured by the chaos checkpoint segment.
 
 Corruption policy: any checksum / format / truncation problem raises
 the typed `CheckpointCorrupt`; `restore_or_none` maps it (and I/O

@@ -345,8 +345,8 @@ def plan_from_env_source() -> str | None:
 
 def install_from_env() -> bool:
     """Install the `CST_FAULTS` plan when the knob is set; returns
-    whether injection is now active.  Call sites: bench_serve / the
-    chaos harness — never at import."""
+    whether injection is now active.  Call sites: the chaos harness —
+    never at import."""
     source = os.environ.get("CST_FAULTS")
     if not source:
         return active()

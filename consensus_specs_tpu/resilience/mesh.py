@@ -28,8 +28,7 @@ This module closes it:
 Accounting: `mesh::recovery_latency_s` (first failure → recovered
 verdict), `mesh.device_lost` / `mesh.readmitted` counters, and
 `block()` — the `"mesh"` sub-object of the chaos round's resilience
-block that `telemetry.history` mines into `mesh::*` records (the
-`mesh-recovery` / `mesh-lost-statements` benchwatch threshold rows).
+block and of `ServeExecutor.stats()`.
 
 Zero wrong or dropped statements is the contract the chaos mesh
 segment (`resilience.chaos._mesh_segment`) measures against the
@@ -318,14 +317,9 @@ class MeshVerifier:
     # --- accounting (the "mesh" resilience sub-block) ------------------------
 
     def block(self) -> dict:
-        """JSON-able `"mesh"` sub-object for the resilience bench block
-        (mined by `telemetry.history.mesh_records`).  `recovered` is
-        the 0/1 gate surface: every observed loss produced a recovered
-        verdict and nothing was dropped — emitted as its own record so
-        an UNRECOVERED round FAILs the `mesh-recovered` threshold row
-        instead of leaving the previous round's latency PASS standing
-        (the recovery-latency record carries value null then, which a
-        numeric threshold cannot see)."""
+        """JSON-able `"mesh"` sub-object for the resilience block.
+        `recovered` is the 0/1 gate surface: every observed loss
+        produced a recovered verdict and nothing was dropped."""
         state = self.state
         last = (self.recovery_latencies[-1]
                 if self.recovery_latencies else None)

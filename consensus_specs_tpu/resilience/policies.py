@@ -178,7 +178,7 @@ class BreakerRegistry:
         return sum(b.trips for b in self._breakers.values())
 
     def summary(self) -> dict:
-        """JSON-able block for the bench `resilience` sub-object."""
+        """JSON-able block for the `resilience` sub-object."""
         return {
             "states": self.states(),
             "trips": self.trips(),

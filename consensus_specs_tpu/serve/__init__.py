@@ -12,8 +12,7 @@ batch N+1 has been dispatched).
 
 `serve.loadgen` drives the executor at (multiples of) mainnet per-slot
 rates and reports steady-state verifies/sec plus p50/p99 batch latency;
-`python -m consensus_specs_tpu.serve` is the CLI, `bench_serve.py` the
-benchwatch-emitting harness.
+`python -m consensus_specs_tpu.serve` is the CLI.
 
 Import discipline: this package init imports ONLY `futures` eagerly —
 the ops device modules import `serve.futures` for their async APIs, and

@@ -2,9 +2,8 @@
 attestation-verification service harness and print the serve block.
 
 Flags mirror the CST_SERVE_* env knobs (flags win); stdout is one JSON
-object (the `"serve"` block `bench_serve.py` embeds in its metric
-lines), the human summary goes to stderr.  `JAX_PLATFORMS=cpu` runs the
-whole thing on the host backend (the CI smoke shape)."""
+object (the `"serve"` block), the human summary goes to stderr.
+`JAX_PLATFORMS=cpu` runs the whole thing on the host backend."""
 
 from __future__ import annotations
 

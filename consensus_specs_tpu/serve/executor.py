@@ -96,7 +96,7 @@ Telemetry (env-gated like everything else): `serve.queue_depth` and
 `serve.inflight_batches` gauges (exported as Chrome-trace counter
 tracks next to the device-memory ones), spans per pump/settle, and
 submitted/settled/failed/recheck/retry/fallback/shed counters.
-Queue-depth and latency accounting for the bench contract is kept
+Queue-depth and latency accounting for the serve block is kept
 independently in plain members (`stats()`, `latencies_s`) so the serve
 block never depends on CST_TELEMETRY.
 """
@@ -960,7 +960,7 @@ class ServeExecutor:
     def _maybe_dump_status(self) -> None:
         """The CST_SERVE_STATUS_EVERY hook: at most one status line per
         interval, as `serve_status: {...}` on stderr (stdout stays the
-        benches' one-JSON-line-per-metric contract)."""
+        CLI's one JSON object)."""
         if self._status_every <= 0:
             return
         now = time.perf_counter()
@@ -972,7 +972,7 @@ class ServeExecutor:
               file=sys.stderr, flush=True)
 
     def stats(self) -> dict:
-        """Plain-dict accounting for the bench `"serve"` block (does not
+        """Plain-dict accounting for the `"serve"` block (does not
         depend on CST_TELEMETRY)."""
         out = {
             "submitted": self._submitted,

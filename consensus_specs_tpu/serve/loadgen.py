@@ -245,8 +245,7 @@ def _fc_payload(n_blocks: int = 48, n_validators: int = 256,
                 batch: int = FC_BATCH_MESSAGES):
     """A synthetic proto-array store plus an infinite attestation-batch
     stream — the `submit_attestation_batch`/`submit_head_request`
-    lane's payload (`forkchoice.synthetic`, the same builder the bench
-    worker sweeps)."""
+    lane's payload (`forkchoice.synthetic`)."""
     from ..forkchoice.synthetic import attestation_stream, synthetic_store
 
     store, roots = synthetic_store(n_blocks, n_validators, seed=53)
@@ -302,10 +301,9 @@ def _proof_payload(n_leaves: int = 256, batch: int = 16):
 
 # self-scrape artifact (written whenever the CST_METRICS_PORT endpoint
 # is live during a measured load): the exposition text exactly as an
-# external Prometheus would have seen it, validated line-by-line by
-# bench_smoke's serve round.  The round scrapes mid-load and again
-# after the drain; the kept snapshot is the latest one, so the
-# artifact carries every served kind as a labeled series
+# external Prometheus would have seen it.  The round scrapes mid-load
+# and again after the drain; the kept snapshot is the latest one, so
+# the artifact carries every served kind as a labeled series
 SCRAPE_ARTIFACT = "out/metrics_scrape.txt"
 
 
@@ -482,7 +480,7 @@ def _default_executor(cfg: LoadConfig) -> ServeExecutor:
 
 def run_load(cfg: LoadConfig | None = None, executor=None) -> dict:
     """Drive the serve executor with the configured load; returns the
-    bench `"serve"` block (schema pinned by
+    `"serve"` block (schema pinned by
     `telemetry.export.validate_serve_block`).
 
     `CST_SERVE_CHAOS=1` delegates to the chaos harness
@@ -580,8 +578,8 @@ def run_load(cfg: LoadConfig | None = None, executor=None) -> dict:
     # the endpoint and status provider are still wired, and with the
     # queue drained every served kind has completed — so the artifact
     # always carries the full per-kind `cst_serve_requests_total`
-    # series set (bench_smoke asserts exactly that; a slow-to-warm
-    # kind can be absent from the mid-round snapshot)
+    # series set (a slow-to-warm kind can be absent from the mid-round
+    # snapshot)
     scrape_text = scrape_live_endpoint() or scrape_text
     if scrape_text is not None:
         write_scrape_artifact(scrape_text)

@@ -1,12 +1,12 @@
 """Env-gated telemetry for the device hot path — spans, counters,
-histograms, and three exporters.
+histograms, and two exporters.
 
 The observability backbone the ROADMAP's perf items read: every open
 question there (compile-vs-execute split of the 81s attestation
 first-call, the `_MSM_DEVICE_MIN=16` host/device break-even, bucket
-padding waste, tier-1 wall-time attribution) is answered from this
-registry rather than a single end-to-end number — the decomposition-
-first methodology of the committee-signature measurement literature
+padding waste) is answered from this registry rather than a single
+end-to-end number — the decomposition-first methodology of the
+committee-signature measurement literature
 (arXiv:2302.00418, arXiv:2602.06655).
 
 Gates (all collection OFF by default, disabled paths are a flag check
@@ -38,22 +38,14 @@ Surface:
     snapshot()            the whole registry as a dict (stable schema)
     reset(), configure(), enabled()
     write_jsonl(path), write_chrome_trace(path), chrome_trace()
-    bench_block(), validate_bench_block()   the bench JSON sub-object
 
 Cost model (`costmodel` submodule, gated CST_TELEMETRY + CST_COSTMODEL):
 per-kernel XLA cost/memory analysis (`costmodel.capture`), roofline
 utilization + compute/memory/launch-bound classification against the
 per-backend peak registry (`costmodel.block`), and per-device live-
 buffer watermarks sampled at span boundaries
-(`costmodel.sample_watermark`).  Flows into `snapshot()["costmodel"]`,
-the bench `"telemetry"` sub-object, the Chrome trace ('C' counter
-events), and the benchwatch report's Utilization section.
-
-Benchwatch (longitudinal layer, not re-exported here): `history.py`
-ingests bench/telemetry rounds into the schema-versioned
-`out/bench_history.jsonl` store, and `python -m
-consensus_specs_tpu.telemetry.report` renders the trend/threshold/
-attribution dashboard and gates on regressions.
+(`costmodel.sample_watermark`).  Flows into `snapshot()["costmodel"]`
+and the Chrome trace ('C' counter events).
 
 Live monitoring (`metrics_export` + `monitor` submodules): a zero-dep
 Prometheus text-exposition endpoint (`CST_METRICS_PORT`) publishing the
@@ -62,16 +54,14 @@ declarative SLO watchdog (`CST_SLO_RULES` rules, rolling windows,
 breach→clear hysteresis, typed `SloBreach` events with worst-N reqtrace
 exemplars and an optional `CST_PROFILE_ON_BREACH` profiler grab).  The
 watchdog's round summary rides the serve block (`"slo"` sub-object,
-`validate_slo_block`), is mined into `slo::*` history records, and
-renders as the report's "SLO" section.
+`validate_slo_block`).
 
 Occupancy + flight recorder (`occupancy` + `flightrec` submodules):
 the per-device busy/bubble interval ledger (`CST_OCCUPANCY`) that
 attributes every idle gap in the serve pipeline to {host_prep,
 queue_starved, settle_serialized, drain} and scores how much host prep
 hid under device wall (the serve block's `"occupancy"` sub-object,
-`pipeline::*` history records, the report's "Pipeline occupancy"
-section, per-device Chrome busy tracks, `cst_serve_device_busy_frac`
+per-device Chrome busy tracks, `cst_serve_device_busy_frac`
 exposition), and the bounded cross-stack incident event ring whose
 `dump_bundle()` freezes breaker/fault/mesh/SLO/occupancy evidence into
 one self-contained directory on watchdog breach, poison storm, or
@@ -99,20 +89,13 @@ from .core import (
     span_seconds,
 )
 from .export import (
-    bench_block,
     chrome_trace,
-    embed_bench_block,
-    validate_bench_block,
     validate_checkpoint_block,
     validate_costmodel_block,
-    validate_das_block,
-    validate_das_producer_block,
-    validate_forkchoice_block,
     validate_latency_attribution,
     validate_mesh_block,
     validate_occupancy_block,
     validate_resilience_block,
-    validate_scaling_block,
     validate_serve_block,
     validate_slo_block,
     write_chrome_trace,
@@ -125,14 +108,11 @@ __all__ = [
     "monitor", "observe", "occupancy", "profiled_spans", "reqtrace",
     "reset",
     "set_meta",
-    "snapshot", "span", "span_seconds", "bench_block", "chrome_trace",
-    "embed_bench_block", "validate_bench_block",
+    "snapshot", "span", "span_seconds", "chrome_trace",
     "validate_checkpoint_block", "validate_costmodel_block",
-    "validate_das_block", "validate_das_producer_block",
-    "validate_forkchoice_block",
     "validate_latency_attribution",
     "validate_mesh_block", "validate_occupancy_block",
-    "validate_resilience_block", "validate_scaling_block",
+    "validate_resilience_block",
     "validate_serve_block", "validate_slo_block",
     "write_chrome_trace", "write_jsonl",
 ]

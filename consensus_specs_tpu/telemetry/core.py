@@ -32,8 +32,7 @@ Enabled, the registry is a process singleton guarded by one lock:
 dispatch of a given (kernel, padded-shape) pair pays trace+XLA-compile
 (or a persistent-cache load), every later dispatch is pure run — so the
 instrumentation routes the first wall sample to `kernel.compile_first_s`
-and the rest to `kernel.run_s`, which is exactly the split the bench
-JSON contract reports (`export.bench_block`).
+and the rest to `kernel.run_s`.
 """
 
 from __future__ import annotations

@@ -294,7 +294,7 @@ def record_cost(kernel: str, flops: float, bytes_accessed: float,
                 device_kind: str | None = None,
                 run_s_probe: float | None = None,
                 memory: dict | None = None) -> None:
-    """Direct cost-record injection (tests and synthetic report rounds);
+    """Direct cost-record injection (tests, and kernels XLA cannot see into);
     same gating and once-per-key semantics as `capture`."""
     if not enabled():
         return
@@ -462,10 +462,9 @@ def join_record(raw: dict, hists: dict) -> dict:
 
 
 def block(hists: dict | None = None) -> dict | None:
-    """The `"costmodel"` sub-object for the bench `"telemetry"` block:
-    every captured kernel joined + classified, plus the watermark
-    summary and the peak registry actually used.  None while disabled
-    (the bench contract omits the key)."""
+    """The `"costmodel"` block: every captured kernel joined +
+    classified, plus the watermark summary and the peak registry
+    actually used.  None while disabled."""
     if not enabled():
         return None
     if hists is None:

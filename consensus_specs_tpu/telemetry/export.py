@@ -1,6 +1,7 @@
-"""Telemetry exporters: JSON-lines, Chrome trace-event, bench sub-object.
+"""Telemetry exporters (JSON-lines, Chrome trace-event) and the schema
+checks of the blocks the serve and chaos rounds return.
 
-Three consumers, three formats:
+Two consumers, two formats:
 
 - `write_jsonl(path)`     one JSON object per line (spans as emitted),
                           greppable / `jq`-able post-hoc.
@@ -9,12 +10,6 @@ Three consumers, three formats:
                           loadable in Perfetto / chrome://tracing.
                           Armed automatically at process exit when
                           `CST_TRACE_FILE` is set.
-- `bench_block()`         the `"telemetry"` sub-object embedded in the
-                          bench JSON contract (`bench.py` / `bench_bls
-                          .py`): the flagship split into compile_s vs
-                          run_s, bucket-padding waste, and MSM/h2c
-                          routing counts.  `validate_bench_block` pins
-                          the schema for `bench_smoke.py` and the tests.
 """
 
 from __future__ import annotations
@@ -124,97 +119,6 @@ def write_chrome_trace(path: str) -> None:
               file=sys.stderr)
 
 
-# --- bench contract ---------------------------------------------------------
-
-
-def bench_block(compile_s: float | None = None,
-                run_s: float | None = None) -> dict:
-    """Assemble the `"telemetry"` sub-object for a bench JSON line from
-    the live registry.  compile_s/run_s default to the kernel-dispatch
-    histograms (`kernel.compile_first_s` / `kernel.run_s` — see
-    `core.first_call`); a bench that times its own jit entry point
-    (bench.py's epoch `step`) passes explicit values instead."""
-    from . import costmodel
-
-    snap = core.snapshot()
-    h = snap["histograms"]
-    c = snap["counters"]
-    if compile_s is None:
-        compile_s = h.get("kernel.compile_first_s", {}).get("total", 0.0)
-    if run_s is None:
-        run_s = h.get("kernel.run_s", {}).get("total", 0.0)
-    live = c.get("bls.lanes.live", 0)
-    padded = c.get("bls.lanes.padded", 0)
-    cm = costmodel.block(h)
-    out = {
-        "compile_s": round(float(compile_s), 4),
-        "run_s": round(float(run_s), 4),
-        # process-level meta (compile-cache dir + entry count, ...) —
-        # survives per-config resets, see core.reset
-        "meta": snap["meta"],
-        "padding": {
-            "live_lanes": live,
-            "padded_lanes": padded,
-            "waste_frac": round(1.0 - live / padded, 4) if padded else 0.0,
-        },
-        "routing": {
-            "msm_host": c.get("msm.route.host", 0),
-            "msm_device": c.get("msm.route.device", 0),
-            "msm_pippenger": c.get("msm.algo.pippenger", 0),
-            "msm_double_add": c.get("msm.algo.double-add", 0),
-            "h2c_device": c.get("bls.h2c.device", 0),
-            "h2c_host": c.get("bls.h2c.host", 0),
-        },
-        "counters": snap["counters"],
-    }
-    if cm is not None:   # CST_COSTMODEL rounds: joined roofline records
-        out["costmodel"] = cm
-    return out
-
-
-def validate_bench_block(obj) -> list[str]:
-    """Schema check for a bench `"telemetry"` sub-object; returns a list
-    of problems (empty == valid).  Used by `bench_smoke.py` and
-    `tests/test_telemetry.py` so the bench contract cannot silently
-    drop or malform the block."""
-    problems: list[str] = []
-    if not isinstance(obj, dict):
-        return [f"telemetry block is {type(obj).__name__}, not dict"]
-    for key in ("compile_s", "run_s"):
-        v = obj.get(key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) or v < 0:
-            problems.append(f"{key!r} must be a non-negative number, "
-                            f"got {v!r}")
-    pad = obj.get("padding")
-    if not isinstance(pad, dict):
-        problems.append("'padding' must be a dict")
-    else:
-        for key in ("live_lanes", "padded_lanes"):
-            if not isinstance(pad.get(key), int):
-                problems.append(f"padding[{key!r}] must be an int")
-        wf = pad.get("waste_frac")
-        if not isinstance(wf, (int, float)) or not (0.0 <= wf <= 1.0):
-            problems.append("padding['waste_frac'] must be in [0, 1]")
-    routing = obj.get("routing")
-    if not isinstance(routing, dict):
-        problems.append("'routing' must be a dict")
-    else:
-        for key in ("msm_host", "msm_device", "msm_pippenger",
-                    "msm_double_add", "h2c_device", "h2c_host"):
-            v = routing.get(key)
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                problems.append(f"routing[{key!r}] must be a "
-                                f"non-negative int, got {v!r}")
-    if not isinstance(obj.get("counters"), dict):
-        problems.append("'counters' must be a dict")
-    if not isinstance(obj.get("meta", {}), dict):
-        problems.append("'meta' must be a dict when present")
-    cm = obj.get("costmodel")
-    if cm is not None:
-        problems.extend(validate_costmodel_block(cm))
-    return problems
-
-
 _BOUNDS = ("compute", "memory", "launch", "unknown")
 
 
@@ -262,10 +166,9 @@ def validate_costmodel_block(cm) -> list[str]:
 
 
 def validate_serve_block(obj) -> list[str]:
-    """Schema check for the bench `"serve"` sub-object (the sustained-
-    load block `serve.loadgen.run_load` returns and `bench_serve.py`
-    embeds); returns problems (empty == valid).  Pinned by
-    `bench_smoke.py`'s serve round and `tests/test_serve.py`."""
+    """Schema check for the `"serve"` block (the sustained-load block
+    `serve.loadgen.run_load` returns); returns problems (empty ==
+    valid).  Pinned by `tests/test_serve.py`."""
     problems: list[str] = []
     if not isinstance(obj, dict):
         return [f"serve block is {type(obj).__name__}, not dict"]
@@ -343,8 +246,7 @@ _SLO_PHASES = ("breach", "clear")
 def validate_slo_block(obj) -> list[str]:
     """Schema check for the serve block's `"slo"` sub-object
     (`telemetry.monitor.Watchdog.slo_block`); returns problems (empty
-    == valid).  Pinned by `bench_smoke.py`'s serve/chaos rounds and
-    tests/test_monitor.py."""
+    == valid).  Pinned by tests/test_monitor.py."""
     if not isinstance(obj, dict):
         return [f"slo block is {type(obj).__name__}, not dict"]
     problems: list[str] = []
@@ -412,8 +314,7 @@ def validate_occupancy_block(obj) -> list[str]:
     (`telemetry.occupancy.block`); returns problems (empty == valid).
     Enforces the contiguity contract: busy plus the four bubble
     components must sum to the measured wall within 1e-6 relative.
-    Pinned by `bench_smoke.py`'s serve round and
-    tests/test_occupancy.py."""
+    Pinned by tests/test_occupancy.py."""
     if not isinstance(obj, dict):
         return [f"occupancy block is {type(obj).__name__}, not dict"]
     problems: list[str] = []
@@ -498,8 +399,7 @@ _LATENCY_OUTCOMES = ("ok", "recheck", "retry", "fallback", "shed",
 def validate_latency_attribution(obj) -> list[str]:
     """Schema check for the serve block's `latency_attribution`
     sub-object (`telemetry.reqtrace.attribution`); returns problems
-    (empty == valid).  Pinned by `bench_smoke.py`'s traced serve round
-    and tests/test_reqtrace.py."""
+    (empty == valid).  Pinned by tests/test_reqtrace.py."""
     if not isinstance(obj, dict):
         return [f"latency_attribution is {type(obj).__name__}, not dict"]
     problems: list[str] = []
@@ -567,8 +467,7 @@ def validate_latency_attribution(obj) -> list[str]:
 def validate_resilience_block(obj) -> list[str]:
     """Schema check for a chaos round's `"resilience"` sub-object
     (`resilience.chaos.run_chaos_load`); returns problems (empty ==
-    valid).  Pinned by `bench_smoke.py --chaos` and
-    tests/test_resilience.py."""
+    valid).  Pinned by tests/test_resilience.py."""
     problems: list[str] = []
     if not isinstance(obj, dict):
         return [f"resilience block is {type(obj).__name__}, not dict"]
@@ -703,188 +602,3 @@ def validate_mesh_block(mesh) -> list[str]:
         problems.append(f"mesh['recovery_latency_s'] must be a "
                         f"non-negative number or null, got {rl!r}")
     return problems
-
-
-def validate_scaling_block(obj) -> list[str]:
-    """Schema check for the bench `"scaling"` sub-object (the
-    mesh-sharded flagship rung ladder `bench.py --worker scaling`
-    emits); returns problems (empty == valid).  Pinned by
-    `bench_smoke.py --shard`."""
-    if not isinstance(obj, dict):
-        return [f"scaling block is {type(obj).__name__}, not dict"]
-    problems: list[str] = []
-    nd = obj.get("n_devices")
-    if not isinstance(nd, int) or isinstance(nd, bool) or nd < 1:
-        problems.append(f"'n_devices' must be a positive int, got {nd!r}")
-    rungs = obj.get("rungs")
-    if not isinstance(rungs, list) or not rungs:
-        return problems + ["'rungs' must be a non-empty list"]
-    for i, r in enumerate(rungs):
-        if not isinstance(r, dict):
-            problems.append(f"rungs[{i}] is not a dict")
-            continue
-        for key in ("n_validators", "n_devices"):
-            v = r.get(key)
-            if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-                problems.append(f"rungs[{i}][{key!r}] must be a "
-                                f"positive int, got {v!r}")
-        for key in ("wall_s", "per_chip_vps", "single_chip_wall_s",
-                    "single_chip_vps", "efficiency"):
-            v = r.get(key)
-            if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                    or v < 0:
-                problems.append(f"rungs[{i}][{key!r}] must be a "
-                                f"non-negative number, got {v!r}")
-    ok8 = obj.get("ok_8m")
-    if ok8 is not None and not isinstance(ok8, bool):
-        problems.append(f"'ok_8m' must be a bool or null, got {ok8!r}")
-    return problems
-
-
-def validate_das_block(obj) -> list[str]:
-    """Schema check for the bench `"das"` sub-object (the PeerDAS
-    cell-proof sampling-matrix sweep `bench.py --worker das` emits);
-    returns problems (empty == valid).  Pinned by `bench_smoke.py
-    --das` and tests/test_das.py."""
-    if not isinstance(obj, dict):
-        return [f"das block is {type(obj).__name__}, not dict"]
-    problems: list[str] = []
-    matrix = obj.get("matrix")
-    if not isinstance(matrix, dict):
-        problems.append("'matrix' must be a dict")
-    else:
-        for key in ("columns", "blobs", "cells"):
-            v = matrix.get(key)
-            if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-                problems.append(f"matrix[{key!r}] must be a positive "
-                                f"int, got {v!r}")
-        if (isinstance(matrix.get("columns"), int)
-                and isinstance(matrix.get("blobs"), int)
-                and isinstance(matrix.get("cells"), int)
-                and matrix["cells"] !=
-                matrix["columns"] * matrix["blobs"]):
-            problems.append("matrix['cells'] must equal columns * blobs")
-    for key in ("verify_wall_s", "cells_per_s", "oracle_wall_s",
-                "speedup"):
-        v = obj.get(key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                or v <= 0:
-            problems.append(f"{key!r} must be a positive number, "
-                            f"got {v!r}")
-    for key in ("oracle_cells_measured", "rung"):
-        v = obj.get(key)
-        if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-            problems.append(f"{key!r} must be a positive int, got {v!r}")
-    if obj.get("batch_verdict") is not True:
-        problems.append("'batch_verdict' must be True (the swept "
-                        "matrix is valid by construction)")
-    iso = obj.get("isolate")
-    if not isinstance(iso, dict) or not isinstance(
-            iso.get("isolated"), bool):
-        problems.append("'isolate' must carry a bool 'isolated' (the "
-                        "mixed-invalid recheck arc)")
-    if not isinstance(obj.get("eval_crosscheck"), bool):
-        problems.append("'eval_crosscheck' must be a bool (the coset "
-                        "barycentric agreement check)")
-    return problems
-
-
-def validate_das_producer_block(obj) -> list[str]:
-    """Schema check for the bench `"das_producer"` sub-object (the FK20
-    producer + erasure-recovery sweep `bench.py --worker das` emits);
-    returns problems (empty == valid).  Pinned by `bench_smoke.py
-    --das` and tests/test_das.py."""
-    if not isinstance(obj, dict):
-        return [f"das_producer block is {type(obj).__name__}, not dict"]
-    problems: list[str] = []
-    for key in ("produce_wall_s", "produce_first_s", "proofs_per_s",
-                "du_wall_s", "producer_speedup"):
-        v = obj.get(key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                or v <= 0:
-            problems.append(f"{key!r} must be a positive number, "
-                            f"got {v!r}")
-    v = obj.get("du_msms_measured")
-    if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-        problems.append(f"'du_msms_measured' must be a positive int, "
-                        f"got {v!r}")
-    if obj.get("parity") is not True:
-        problems.append("'parity' must be True (FK20 proofs byte-equal "
-                        "the closed-form ground truth)")
-    rec = obj.get("recover")
-    if not isinstance(rec, dict):
-        problems.append("'recover' must be a dict")
-        return problems
-    for key in ("wall_s", "oracle_wall_s", "speedup"):
-        v = rec.get(key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                or v <= 0:
-            problems.append(f"recover[{key!r}] must be a positive "
-                            f"number, got {v!r}")
-    for key in ("cells_in", "missing", "oracle_cosets_measured"):
-        v = rec.get(key)
-        if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-            problems.append(f"recover[{key!r}] must be a positive int, "
-                            f"got {v!r}")
-    if isinstance(rec.get("cells_in"), int) and rec["cells_in"] < 64:
-        problems.append("recover['cells_in'] must be >= 64 (half the "
-                        "extended blob — below that nothing is "
-                        "recoverable)")
-    if rec.get("roundtrip") is not True:
-        problems.append("'recover.roundtrip' must be True (recovered "
-                        "cells and proofs byte-equal the originals)")
-    return problems
-
-
-def validate_forkchoice_block(obj) -> list[str]:
-    """Schema check for the bench `"forkchoice"` sub-object (the
-    device LMD-GHOST sweep `bench.py --worker forkchoice` emits);
-    returns problems (empty == valid).  Pinned by `bench_smoke.py
-    --forkchoice` and tests/test_forkchoice.py."""
-    if not isinstance(obj, dict):
-        return [f"forkchoice block is {type(obj).__name__}, not dict"]
-    problems: list[str] = []
-    tree = obj.get("tree")
-    if not isinstance(tree, dict):
-        problems.append("'tree' must be a dict")
-    else:
-        for key in ("blocks", "validators", "messages"):
-            v = tree.get(key)
-            if not isinstance(v, int) or isinstance(v, bool) or v < 1:
-                problems.append(f"tree[{key!r}] must be a positive "
-                                f"int, got {v!r}")
-    for key in ("apply_wall_s", "head_wall_s", "heads_per_s",
-                "oracle_head_wall_s", "speedup"):
-        v = obj.get(key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                or v <= 0:
-            problems.append(f"{key!r} must be a positive number, "
-                            f"got {v!r}")
-    if not isinstance(obj.get("oracle_validators_measured"), int) \
-            or isinstance(obj.get("oracle_validators_measured"), bool) \
-            or obj.get("oracle_validators_measured") < 1:
-        problems.append("'oracle_validators_measured' must be a "
-                        "positive int")
-    rungs = obj.get("rungs")
-    if not isinstance(rungs, dict) or not all(
-            isinstance(rungs.get(k), int) and not
-            isinstance(rungs.get(k), bool) and rungs.get(k) >= 1
-            for k in ("blocks", "validators", "batch")):
-        problems.append("'rungs' must carry positive int "
-                        "blocks/validators/batch ladder shapes")
-    if obj.get("parity") is not True:
-        problems.append("'parity' must be True (the device head must "
-                        "match the spec oracle's on the swept tree)")
-    return problems
-
-
-def embed_bench_block(record: dict) -> dict:
-    """The shared per-config bench protocol: attach the current
-    `"telemetry"` block to a metric record and reset the per-config
-    aggregates so the next config's counters start clean.  No-op while
-    telemetry is off.  Used by both `bench.py` and `bench_bls.py` — one
-    copy of the protocol."""
-    if core.enabled():
-        record["telemetry"] = bench_block()
-        core.reset()
-    return record

@@ -1,7 +1,7 @@
 """Prometheus text-format exposition of the live telemetry registry.
 
 Every observability layer before this one ends at an artifact read
-AFTER a round finishes (bench blocks, history records, Chrome traces).
+AFTER a round finishes (the serve block, Chrome traces).
 This module is the live surface: a zero-dependency HTTP endpoint
 (stdlib `http.server`, daemon thread, armed by `CST_METRICS_PORT`)
 rendering the whole registry in the Prometheus text exposition format
@@ -22,8 +22,8 @@ and counted (`metrics.name_collision`), and the analyzer rule
 `metric-name-invalid` makes the source-level invariant a lint check.
 
 `render_exposition()` is pure (registry snapshot -> text) and
-`parse_exposition()` is its validating inverse — the scrape artifact
-check in bench_smoke and the round-trip test both go through it.
+`parse_exposition()` is its validating inverse, which the round-trip
+test goes through.
 
 Gating contract (the telemetry pattern): the server only starts when
 `CST_METRICS_PORT` is set (or `start()` is called explicitly); nothing
@@ -312,8 +312,8 @@ _LABEL_PAIR_RE = re.compile(
 def parse_exposition(text: str) -> dict:
     """Parse (and strictly validate) exposition text, returning
     `{metric_name: [(labels_dict, value), ...]}`.  Raises ValueError
-    naming the first malformed line — the line-by-line format check the
-    bench-smoke scrape validation and the round-trip test share."""
+    naming the first malformed line — the line-by-line format check of
+    the round-trip test."""
     out: dict[str, list] = {}
     typed: set[str] = set()
     for lineno, line in enumerate(text.splitlines(), 1):
@@ -438,8 +438,8 @@ def serving_port() -> int | None:
 
 def start_from_env() -> int | None:
     """Start the endpoint when `CST_METRICS_PORT` is set (non-"0");
-    returns the bound port or None.  Call sites: loadgen / bench_serve /
-    the chaos harness — never at import."""
+    returns the bound port or None.  Call sites: loadgen and the chaos
+    harness — never at import."""
     raw = os.environ.get("CST_METRICS_PORT", "")
     if raw in ("", "0"):
         return serving_port()

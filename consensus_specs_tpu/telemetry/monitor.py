@@ -637,8 +637,8 @@ class Watchdog:
         return [st.name for st in self.rules if st.breaching]
 
     def slo_block(self) -> dict:
-        """The round-summary sub-object (rides the serve/resilience
-        bench block; mined into `slo::*` history records)."""
+        """The round-summary sub-object (rides the serve and
+        resilience blocks as `"slo"`)."""
         with self._lock:
             events = [e.as_dict() for e in self._events]
             dropped = self._events_dropped
@@ -749,8 +749,7 @@ def install_from_env(status_provider=None,
     (`slo.rules_invalid`) instead of an exception — a typo'd knob must
     not kill a serve round.  Also starts the `CST_METRICS_PORT`
     exposition endpoint (the two arm together on the pod checklist).
-    Call sites: loadgen / bench_serve / the chaos harness — never at
-    import."""
+    Call sites: loadgen and the chaos harness — never at import."""
     metrics_export.start_from_env()
     if status_provider is not None:
         metrics_export.set_status_provider(status_provider)

@@ -43,9 +43,8 @@ same contiguity contract as reqtrace's five latency components; pinned
 to 1e-6 relative by tests/test_occupancy.py).
 
 Read sides: the serve block's `"occupancy"` sub-object
-(`telemetry.export.validate_occupancy_block`), `pipeline::*` history
-records, the report's "Pipeline occupancy" section + `serve-occupancy`
-threshold row, Chrome-trace per-device busy counter tracks, the
+(`telemetry.export.validate_occupancy_block`), Chrome-trace
+per-device busy counter tracks, the
 `cst_serve_device_busy_frac` / `cst_serve_bubble_seconds_total{cause=}`
 exposition families, `ServeExecutor.status()["occupancy"]`, and the
 watchdog's `serve.busy_frac` signal.

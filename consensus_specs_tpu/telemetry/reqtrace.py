@@ -40,8 +40,7 @@ problem every batched-inference server solves:
   lineage the Chrome-trace flow events render as arrows.
 - `attribution()`: per-kind p50/p90/p99 decomposed into the five
   components, worst-N exemplar traces retained — the serve block's
-  `latency_attribution` sub-object, mined into `latency::*` history
-  records and rendered as the report's "Tail latency" section.
+  `latency_attribution` sub-object.
 - `chrome_events()`: request lifecycle 'X' spans + 's'/'t'/'f' flow
   events (submit → batch → settle arrows) + batch 'X' spans, appended
   to the existing Perfetto export by `telemetry.export.chrome_trace`.

@@ -131,14 +131,3 @@ def enable_compile_cache() -> None:
         telemetry.set_meta("compile_cache.entries_at_start",
                            sum(1 for p in d.iterdir() if p.is_file())
                            if d.is_dir() else 0)
-
-
-def device_fields() -> dict:
-    """What this process's devices report, stamped on every bench
-    result so that no number is read as another device's."""
-    import jax
-
-    devs = jax.devices()
-    return {"platform": devs[0].platform,
-            "device_kind": devs[0].device_kind,
-            "device_count": len(devs)}

@@ -2,7 +2,8 @@
 
 The suite runs on the CPU, on 8 virtual devices so that the mesh tests
 (`jax.sharding.Mesh` over 8 devices) run on any machine.  The chip is
-exercised by `chip_smoke.py` and `bench.py`, not by the unit suite.
+exercised by `chip_smoke.py` and the benchmark (`benchmark/run.py`), not
+by the unit suite.
 """
 
 import os
@@ -50,17 +51,14 @@ def pytest_addoption(parser):
 
 
 # --- session-scoped oracle reuse (the tier-1 870 s budget) ------------------
-# The ROADMAP's standing trim candidate was "session-scoped spec-build
-# reuse", but the benchwatch tier1-attribution table shows spec builds
-# are ALREADY session-cached (`models.builder._SPEC_CACHE`: <1% of
-# suite wall lands in the spec-build phase) — the budget is eaten by
-# the pure-Python BLS oracle recomputing deterministic work across
-# tests: hash-to-curve of repeated messages, subgroup checks of the
-# same genesis pubkeys in every verify loop, and re-signing identical
-# (privkey, message) pairs.  All of these are pure functions of their
-# byte/int inputs, so the session scope memoizes them here, test-suite
-# only — bench paths must keep measuring real oracle work, and the
-# pairing check itself (the verification verdict) is never cached.
+# Spec builds are already session-cached (`models.builder._SPEC_CACHE`);
+# the budget is eaten by the pure-Python BLS oracle recomputing
+# deterministic work across tests: hash-to-curve of repeated messages,
+# subgroup checks of the same genesis pubkeys in every verify loop, and
+# re-signing identical (privkey, message) pairs.  All of these are pure
+# functions of their byte/int inputs, so the session scope memoizes them
+# here, test-suite only, and the pairing check itself (the verification
+# verdict) is never cached.
 
 
 def _memo(fn, key_fn, cache=None):
@@ -209,7 +207,7 @@ def _session_oracle_reuse():
 # messages, balances, checkpoints, boost, config).  The parity suites
 # re-evaluate identical store states across tests (every device head
 # check re-asks the oracle), so the session scope memoizes the seam on
-# the fingerprint — bench paths measure the unwrapped oracle.
+# the fingerprint.
 
 
 @pytest.fixture(autouse=True, scope="session")
@@ -237,82 +235,3 @@ def _configure_backends(request):
     context.DEFAULT_TEST_PRESET = request.config.getoption("--preset")
     context.DEFAULT_FORK_RESTRICTION = request.config.getoption("--fork")
     yield
-
-
-# --- telemetry attribution (CST_TELEMETRY=1 runs only) ----------------------
-# Each test runs under a span named by its nodeid, so the end-of-session
-# snapshot attributes wall time per test — the tier-1 870s-budget
-# overrun (ROADMAP) gets per-test data on every CI run, alongside
-# pytest's own --durations output.
-#
-# Each test's wall is additionally split into two phase-tagged events,
-# "<nodeid> [spec-build]" vs "<nodeid> [test-body]", by reading the
-# builder's cumulative `spec.build` span before and after the test: the
-# benchwatch attribution table (telemetry.report) uses the split to
-# name which slow tests are really paying for spec namespace builds —
-# the ROADMAP's trim-target question (session compile-cache reuse,
-# redundant spec builds) — and which spend the time in the test body.
-
-_session_t0 = None
-_test_count = 0
-
-
-def pytest_sessionstart(session):
-    global _session_t0
-    import time
-
-    _session_t0 = time.perf_counter()
-
-
-@pytest.fixture(autouse=True)
-def _telemetry_test_span(request):
-    import time
-
-    from consensus_specs_tpu import telemetry
-
-    if not telemetry.enabled():
-        yield
-        return
-    global _test_count
-    _test_count += 1
-    nodeid = request.node.nodeid
-    build0 = telemetry.span_seconds("spec.build")
-    t0 = time.perf_counter()
-    with telemetry.span(nodeid):
-        yield
-    dur = time.perf_counter() - t0
-    # spec builds triggered by THIS test (cache misses inside its span);
-    # clamp to the test wall — a build started by a background thread
-    # must not push the body share negative
-    build = min(max(telemetry.span_seconds("spec.build") - build0, 0.0),
-                dur)
-    telemetry.add_event(f"{nodeid} [spec-build]", build,
-                        phase="spec-build", test=nodeid)
-    telemetry.add_event(f"{nodeid} [test-body]", dur - build,
-                        phase="test-body", test=nodeid)
-
-
-def pytest_sessionfinish(session, exitstatus):
-    """Write the telemetry snapshot where CST_TELEMETRY_OUT points (CI
-    uploads it as an artifact; `telemetry.report` ingests it for the
-    tier-1 attribution table); no-op unless telemetry is collecting."""
-    out = os.environ.get("CST_TELEMETRY_OUT")
-    if not out:
-        return
-    from consensus_specs_tpu import telemetry
-
-    if not telemetry.enabled():
-        return
-    import json
-    import time
-    from pathlib import Path
-
-    if _session_t0 is not None:
-        # the tier-1 870s budget is checked against this (benchwatch's
-        # `tier1_wall_s` metric)
-        telemetry.set_meta("tier1.session_wall_s",
-                           round(time.perf_counter() - _session_t0, 3))
-    telemetry.set_meta("tier1.tests", _test_count)
-    path = Path(out)
-    path.parent.mkdir(parents=True, exist_ok=True)
-    path.write_text(json.dumps(telemetry.snapshot(), indent=1) + "\n")

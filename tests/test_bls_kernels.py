@@ -14,7 +14,8 @@ component-level counterpart of `test_bls_jax.py`'s accept/reject parity:
 All CPU-runnable with small batch buckets (JAX_PLATFORMS=cpu is pinned by
 conftest).  The full hash/pairing programs compile for tens of seconds on
 CPU, so those carry the `slow` marker the same way `test_bls_jax.py`
-does; the host-side and trace-level checks stay in the fast lane.
+does; the host-side and trace-level checks stay in the fast lane, with
+one pairing check of a sync aggregate and one MSM at the bottom rung.
 """
 
 import random
@@ -175,6 +176,39 @@ def test_batch_verify_device_h2c_parity():
     bad[1] = (bad[1][0], bad[1][1], C.g2.mul(C.G2_GEN, 31337))
     assert bb.batch_verify(bad, rng=rng, device_h2c=True) is False
     assert bb.batch_verify(bad, rng=rng, device_h2c=False) is False
+
+
+def _sync_aggregate(n_keys: int, tamper: bool):
+    """The two pairs of a FastAggregateVerify over `n_keys` keys:
+    e(pk, H(m)) == e(G1, sig), with the signature of another message
+    where `tamper`."""
+    msg = b"\x5c" * 32
+    sks = [1000 + i for i in range(n_keys)]
+    pk = C.g1.mul(C.G1_GEN, sum(sks))
+    h = H.hash_to_g2(msg, H.DST_G2)
+    signed = H.hash_to_g2(b"\x5d" * 32, H.DST_G2) if tamper else h
+    return [(pk, h), (C.g1.neg(C.G1_GEN), C.g2.mul(signed, sum(sks)))]
+
+
+@pytest.mark.parametrize("tamper", [False, True],
+                         ids=["valid", "wrong_message"])
+def test_sync_aggregate_pairing_on_device_matches_oracle(tamper):
+    """`pairing_check_device` on a four-key sync aggregate, as the sync
+    committee's FastAggregateVerify asks it, against the oracle."""
+    pairs = _sync_aggregate(4, tamper)
+    assert bb.pairing_check_device(pairs) is P.pairing_check(pairs)
+    assert P.pairing_check(pairs) is not tamper
+
+
+def test_msm_on_device_matches_host_at_the_bottom_rung():
+    """`g1_multi_exp_device` below the host/device break-even
+    (`_MSM_DEVICE_MIN`), where the facade would route to the host: the
+    device kernel still agrees with the host MSM."""
+    rng = random.Random(4)
+    pts = [C.g1.mul(C.G1_GEN, rng.randrange(1, C.R)) for _ in range(4)]
+    ks = [rng.randrange(C.R) for _ in range(4)]
+    assert C.g1.eq_points(bb.g1_multi_exp_device(pts, ks),
+                          C.g1.msm(pts, ks))
 
 
 def test_rlc_kernel_lowering_names_its_stages():

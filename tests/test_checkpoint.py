@@ -1,12 +1,12 @@
 """MerkleForest checkpoint/restore (`resilience/checkpoint.py`):
 snapshot + leaf-delta journal round-trips, SSZ-oracle root parity,
 corrupted-checksum rejection with full-rebuild fallback,
-restore-under-concurrent-update safety, and the `checkpoint`
-benchwatch record kind.
+restore-under-concurrent-update safety, and the `checkpoint` block
+schema.
 
 Small forests (64–256 chunks) keep every test on depths tier-1 already
-compiles; the 2^17-chunk speedup measurement lives in the chaos
-checkpoint segment (`make chaos-smoke`), not here.
+compiles; the 2^20-chunk speedup measurement lives in the chaos
+checkpoint segment (`resilience.chaos`), not here.
 """
 
 from __future__ import annotations
@@ -25,7 +25,6 @@ from consensus_specs_tpu.resilience.checkpoint import (
     env_every,
     manager_from_env,
 )
-from consensus_specs_tpu.telemetry import history as benchwatch
 from consensus_specs_tpu.telemetry import validate_checkpoint_block
 
 
@@ -339,7 +338,7 @@ def test_existing_seq_resumes(tmp_path):
     assert json.loads(fresh.manifest_path.read_text())["seq"] == 3
 
 
-# --- the checkpoint record kind / report surfaces ----------------------------
+# --- the checkpoint block schema --------------------------------------------
 
 
 def test_checkpoint_block_validation_and_records():
@@ -350,79 +349,3 @@ def test_checkpoint_block_validation_and_records():
     assert validate_checkpoint_block(block) == []
     assert validate_checkpoint_block(None) == []
     assert validate_checkpoint_block({"parity": "yes"})
-    records = benchwatch.checkpoint_records("serve_sustained_load",
-                                            block, platform="cpu",
-                                            ts=9.0)
-    by_metric = {r["metric"]: r for r in records}
-    assert set(by_metric) == {"checkpoint::restore",
-                              "checkpoint::journal_entries",
-                              "checkpoint::snapshot_bytes"}
-    rec = by_metric["checkpoint::restore"]
-    assert benchwatch.validate_record(rec) == []
-    assert rec["source"] == "checkpoint"
-    assert rec["value"] == 0.12 and rec["vs_baseline"] == 10.8
-    assert rec["checkpoint"]["parity"] is True
-    # malformed blocks yield zero records, never a raise
-    assert benchwatch.checkpoint_records("m", None) == []
-    assert benchwatch.checkpoint_records("m", {"restore_s": "slow"}) == []
-
-
-def test_checkpoint_threshold_row():
-    from consensus_specs_tpu.telemetry import report
-
-    rows = {t["id"]: t for t in report.THRESHOLDS}
-    row = rows["checkpoint-restore"]
-    assert row["field"] == "vs_baseline" and row["target"] == 5.0
-    fast = benchwatch.checkpoint_records("m", {
-        "n_chunks": 4, "journal_entries": 1, "journal_frac": 0.01,
-        "snapshot_bytes": 100, "restore_s": 0.1, "rebuild_s": 1.0,
-        "speedup": 10.0, "parity": True}, platform="cpu", ts=1.0)
-    evaluated = {t["id"]: t for t in report.evaluate_thresholds(fast)}
-    assert evaluated["checkpoint-restore"]["status"] == "PASS"
-    slow = benchwatch.checkpoint_records("m", {
-        "n_chunks": 4, "journal_entries": 1, "journal_frac": 0.01,
-        "snapshot_bytes": 100, "restore_s": 1.0, "rebuild_s": 1.5,
-        "speedup": 1.5, "parity": True}, platform="cpu", ts=2.0)
-    evaluated = {t["id"]: t
-                 for t in report.evaluate_thresholds(fast + slow)}
-    assert evaluated["checkpoint-restore"]["status"] == "FAIL"
-
-
-def test_resilience_block_mining_includes_new_sub_blocks():
-    """One chaos-shaped resilience block -> resilience + mesh +
-    checkpoint + flagship records through the ONE mining entry point."""
-    res = {
-        "chaos": True, "faults_injected": 2, "injected_sites": {},
-        "wrong_results": 0, "failed_requests": 0, "checked_results": 10,
-        "recovered": True, "recovery_latency_s": 3.0, "retries": 1,
-        "fallbacks": 2, "shed": 0,
-        "breaker": {"states": {}, "trips": 1, "transitions": []},
-        "heal": {"detected": True, "diverged": True,
-                 "recovery_s": 0.02, "path": "checkpoint",
-                 "n_chunks": 256},
-        "checkpoint": {"n_chunks": 8, "journal_entries": 1,
-                       "journal_frac": 0.01, "snapshot_bytes": 10,
-                       "restore_s": 0.1, "rebuild_s": 1.0,
-                       "speedup": 10.0, "parity": True},
-        "flagship": {"degraded_steps": 2, "wrong_results": 0,
-                     "checked_settles": 4, "recovered": True,
-                     "breaker": {"states": {}, "trips": 1,
-                                 "transitions": []}},
-        "mesh": {"devices": 8, "degraded_lanes": 0,
-                 "max_degraded_lanes": 1, "device_lost_events": 1,
-                 "readmissions": 1, "retrips": 0, "redispatches": 1,
-                 "recoveries": 1, "recovery_latency_s": 0.5,
-                 "verified_statements": 16, "lost_statements": 0,
-                 "wrong_results": 0, "checked_statements": 17,
-                 "readmitted": True},
-    }
-    records = benchwatch.resilience_records("serve_sustained_load", res,
-                                            platform="cpu", ts=1.0)
-    by_metric = {r["metric"]: r for r in records}
-    assert by_metric["resilience::merkle_heal_s"]["heal_path"] \
-        == "checkpoint"
-    assert by_metric["resilience::flagship_degraded_steps"]["value"] == 2
-    assert by_metric["mesh::recovery_latency_s"]["source"] == "mesh"
-    assert by_metric["checkpoint::restore"]["source"] == "checkpoint"
-    for rec in records:
-        assert benchwatch.validate_record(rec) == [], rec

@@ -1,9 +1,8 @@
 """The kernel cost model (`consensus_specs_tpu/telemetry/costmodel.py`):
 XLA cost/memory capture on a jitted toy kernel (exact flops for a known
 matmul), peak-registry classification boundaries, watermark high-water
-monotonicity, snapshot / bench-block / history schemas, the benchwatch
-report's Utilization section over a synthetic costmodel round, and the
-measured no-op bound when CST_COSTMODEL is off."""
+monotonicity, the snapshot and block schemas, and the measured no-op
+bound when CST_COSTMODEL is off."""
 
 import json
 import time
@@ -11,7 +10,7 @@ import time
 import pytest
 
 from consensus_specs_tpu import telemetry
-from consensus_specs_tpu.telemetry import costmodel, history
+from consensus_specs_tpu.telemetry import costmodel
 from consensus_specs_tpu.telemetry import core as tcore
 
 
@@ -81,9 +80,8 @@ def test_capture_failure_stores_error_record_never_raises():
     assert "error" in rec and rec["kernel"] == "broken@1"
     assert telemetry.snapshot()["counters"][
         "costmodel.capture_errors"] == 1
-    # an error record is still schema-valid inside the bench block
-    blk = telemetry.bench_block()
-    assert telemetry.validate_bench_block(blk) == []
+    # an error record is still schema-valid inside the block
+    assert telemetry.validate_costmodel_block(costmodel.block()) == []
 
 
 def test_record_cost_direct_injection():
@@ -208,7 +206,7 @@ def test_watermark_counter_events_in_chrome_trace():
     json.dumps(trace)
 
 
-# --- snapshot / bench-block / history schemas --------------------------------
+# --- snapshot / block schemas ----------------------------------------------
 
 
 def test_block_joins_dispatch_run_hist_over_probe():
@@ -219,16 +217,6 @@ def test_block_joins_dispatch_run_hist_over_probe():
     rec = costmodel.block()["kernels"]["joined@8"]
     assert rec["run_s_mean"] == 1.0          # hist mean, not the probe
     assert rec["run_source"] == "dispatch"
-
-
-def test_bench_block_costmodel_schema_and_json():
-    f, x = _toy_matmul()
-    costmodel.capture("schema@8", f, (x, x))
-    costmodel.sample_watermark("schema")
-    blk = telemetry.bench_block()
-    assert telemetry.validate_bench_block(blk) == []
-    assert telemetry.validate_costmodel_block(blk["costmodel"]) == []
-    json.dumps(blk)
 
 
 def test_validate_costmodel_block_rejects_malformed():
@@ -244,226 +232,6 @@ def test_validate_costmodel_block_rejects_malformed():
                                        "last_bytes": 2}}}
     assert any("high water" in p
                for p in telemetry.validate_costmodel_block(bad_wm))
-
-
-def test_history_records_round_trip(tmp_path):
-    costmodel.record_cost("hist@8", flops=100.0, bytes_accessed=50.0,
-                          run_s_probe=0.1)
-    costmodel.record_cost("msm_tiny@8", flops=1.0, bytes_accessed=1.0,
-                          run_s_probe=0.5)
-    with costmodel._lock:
-        costmodel._watermarks["cpu:0"] = {"last_bytes": 10,
-                                          "high_water_bytes": 20,
-                                          "samples": 2}
-    blk = telemetry.bench_block()
-    recs = history.costmodel_records("some_metric", blk, ts=123.0,
-                                     platform="cpu")
-    metrics = {r["metric"] for r in recs}
-    assert {"costmodel::hist@8", "costmodel::msm_tiny@8",
-            "device_mem_high_water::cpu:0"} <= metrics
-    for r in recs:
-        assert history.validate_record(r) == [], r
-        assert r["source"] == "costmodel"
-    store = tmp_path / "h.jsonl"
-    assert history.append_records(store, recs) == len(recs)
-    loaded, skipped, warns = history.load_history(store)
-    assert (len(loaded), skipped, warns) == (len(recs), 0, [])
-    assert {r["metric"] for r in loaded} == metrics
-
-
-def test_malformed_costmodel_block_yields_no_records():
-    assert history.costmodel_records("m", None) == []
-    assert history.costmodel_records("m", {"costmodel": "nope"}) == []
-    assert history.costmodel_records(
-        "m", {"costmodel": {"kernels": {"k": {"error": "boom"}},
-                            "watermarks": {}}}) == []
-
-
-# --- report: the Utilization section -----------------------------------------
-
-
-def _synthetic_round(tmp_path):
-    """A checked-in-style synthetic costmodel round: one compute-bound
-    kernel, one launch-bound small MSM, a watermark, and an attestation
-    metric with an embedded compile/run split."""
-    recs = [
-        history.make_record(
-            "costmodel", "costmodel::pairing_check@8", 0.2, unit="s",
-            platform="tpu", ts=100.0,
-            costmodel={"kernel": "pairing_check@8", "flops": 2.0e13,
-                       "bytes_accessed": 1.0e10, "run_s_mean": 0.2,
-                       "arithmetic_intensity": 2000.0,
-                       "achieved_flops_per_s": 1.0e14,
-                       "achieved_bytes_per_s": 5.0e10,
-                       "util_flops_pct": 50.8, "util_bw_pct": 6.1,
-                       "bound": "compute", "peak_source": "tpu"}),
-        history.make_record(
-            "costmodel", "costmodel::msm_pippenger@8w4", 0.01, unit="s",
-            platform="tpu", ts=100.0,
-            costmodel={"kernel": "msm_pippenger@8w4", "flops": 1.0e6,
-                       "bytes_accessed": 1.0e5, "run_s_mean": 0.01,
-                       "arithmetic_intensity": 10.0,
-                       "achieved_flops_per_s": 1.0e8,
-                       "achieved_bytes_per_s": 1.0e7,
-                       "util_flops_pct": 0.0, "util_bw_pct": 0.0,
-                       "bound": "launch", "peak_source": "tpu"}),
-        history.make_record(
-            "costmodel", "device_mem_high_water::tpu:0", 123456789,
-            unit="bytes", samples=7, platform="tpu", ts=100.0),
-        history.make_record(
-            "bench_emit", "attestation_batch_128x64_verify_wall", 0.31,
-            unit="s", vs_baseline=31.0, platform="tpu", ts=100.0,
-            telemetry={"compile_s": 81.2, "run_s": 0.31}),
-    ]
-    store = tmp_path / "bench_history.jsonl"
-    assert history.append_records(store, recs) == len(recs)
-    return store
-
-
-def test_report_utilization_golden(tmp_path):
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    store = _synthetic_round(tmp_path)
-    stored, _, _ = history.load_history(store)
-    util = rpt.collect_utilization(stored)
-    assert util["warnings"] == []
-    assert util["kernels"]["pairing_check@8"]["bound"] == "compute"
-    assert util["kernels"]["msm_pippenger@8w4"]["bound"] == "launch"
-    assert util["watermarks"]["tpu:0"]["high_water_bytes"] == 123456789
-    verdict = util["verdict"]
-    assert verdict["kind"] == "compile-bound"
-    assert verdict["compile_s"] == 81.2 and verdict["run_s"] == 0.31
-
-    text = "\n".join(rpt.render_utilization(util, {"status": "keep"}))
-    assert "## Utilization" in text
-    assert "`pairing_check@8`" in text and "**compute**" in text
-    assert "**launch**" in text
-    assert "compile-bound" in text and "81.2" in text
-    assert "msm_pippenger@8w4" in text     # the _MSM_DEVICE_MIN note
-    assert "123.46 MB" in text             # watermark row
-
-
-def test_report_utilization_no_data_renders():
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    util = rpt.collect_utilization([])
-    text = "\n".join(rpt.render_utilization(util, {"status": "no data"}))
-    assert "## Utilization" in text and "No cost-model data" in text
-
-
-def test_report_tpu_records_outrank_cpu(tmp_path):
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    recs = [
-        history.make_record(
-            "costmodel", "costmodel::k@8", 0.1, unit="s",
-            platform="tpu", ts=100.0,
-            costmodel={"kernel": "k@8", "flops": 1.0,
-                       "bytes_accessed": 1.0, "bound": "compute"}),
-        history.make_record(
-            "costmodel", "costmodel::k@8", 0.2, unit="s",
-            platform="cpu", ts=200.0,
-            costmodel={"kernel": "k@8", "flops": 2.0,
-                       "bytes_accessed": 2.0, "bound": "launch"}),
-    ]
-    util = rpt.collect_utilization(recs)
-    assert util["kernels"]["k@8"]["platform"] == "tpu"
-    assert util["kernels"]["k@8"]["bound"] == "compute"
-
-
-def test_report_verdict_prefers_tpu_over_later_cpu_smoke():
-    # the CI CPU smoke round is appended before every report — a later
-    # cpu attestation record must not override the TPU round's
-    # compile-vs-execute verdict
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    recs = [
-        history.make_record(
-            "bench_emit", "attestation_batch_128x64_verify_wall", 0.31,
-            unit="s", platform="tpu", ts=100.0,
-            telemetry={"compile_s": 81.2, "run_s": 0.31}),
-        history.make_record(
-            "bench_emit", "attestation_batch_2x2_verify_wall", 0.7,
-            unit="s", platform="cpu", ts=200.0,
-            telemetry={"compile_s": 40.0, "run_s": 0.7}),
-    ]
-    verdict = rpt.collect_utilization(recs)["verdict"]
-    assert verdict["platform"] == "tpu"
-    assert verdict["compile_s"] == 81.2
-
-
-def test_emission_records_dedupe_cumulative_costmodel():
-    # a bench process emits one metric line per config but the
-    # costmodel block is a cumulative per-process fact: unchanged
-    # kernel/watermark records must land in the store exactly once
-    history._emitted_cost_payloads.clear()
-    cm = {"kernels": {"k@8": {"kernel": "k@8", "flops": 10.0,
-                              "bytes_accessed": 5.0, "run_s_mean": 0.1}},
-          "watermarks": {"cpu:0": {"last_bytes": 4, "high_water_bytes": 8,
-                                   "samples": 2}}}
-    tel = {"compile_s": 1.0, "run_s": 0.1, "costmodel": cm}
-    total = []
-    for i, m in enumerate(("m_a", "m_b", "m_c")):
-        total += history.emission_records(
-            {"metric": m, "value": 1.0, "unit": "s", "vs_baseline": 1.0,
-             "telemetry": tel}, ts=1000.0 + i)
-    cost = [r for r in total if r["source"] == "costmodel"]
-    assert sorted(r["metric"] for r in cost) == [
-        "costmodel::k@8", "device_mem_high_water::cpu:0"]
-    # a grown high-water IS new data — it re-emits
-    cm["watermarks"]["cpu:0"]["high_water_bytes"] = 16
-    more = history.emission_records(
-        {"metric": "m_d", "value": 1.0, "unit": "s", "vs_baseline": 1.0,
-         "telemetry": tel}, ts=1003.0)
-    assert [r["metric"] for r in more if r["source"] == "costmodel"] \
-        == ["device_mem_high_water::cpu:0"]
-    history._emitted_cost_payloads.clear()
-
-
-def test_round_file_costmodel_records_not_duplicated(tmp_path):
-    # three metric lines in one round tail share the cumulative block:
-    # one record per kernel/device, last line wins
-    cm = {"kernels": {"k@8": {"kernel": "k@8", "flops": 10.0,
-                              "bytes_accessed": 5.0, "run_s_mean": 0.1}},
-          "watermarks": {"cpu:0": {"last_bytes": 4, "high_water_bytes": 8,
-                                   "samples": 2}}}
-    tel = {"compile_s": 1.0, "run_s": 0.1, "costmodel": cm}
-    tail = "\n".join(
-        json.dumps({"metric": m, "value": 1.0, "unit": "s",
-                    "vs_baseline": 1.0, "telemetry": tel})
-        for m in ("m_a", "m_b", "m_c"))
-    p = tmp_path / "BENCH_r09.json"
-    p.write_text(json.dumps({"n": 9, "rc": 0, "tail": tail}))
-    recs, warns = history.parse_bench_round(p)
-    assert not warns
-    cost = [r for r in recs if r["source"] == "costmodel"]
-    assert sorted(r["metric"] for r in cost) == [
-        "costmodel::k@8", "device_mem_high_water::cpu:0"]
-
-
-def test_report_malformed_costmodel_is_counted_warning():
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    rec = history.make_record("costmodel", "costmodel::bad@1", 0.1,
-                              unit="s", platform="cpu", ts=1.0,
-                              costmodel={"kernel": "bad@1"})  # no flops
-    util = rpt.collect_utilization([rec])
-    assert util["kernels"] == {}
-    assert len(util["warnings"]) == 1
-
-
-def test_build_report_warns_on_missing_costmodel_round(tmp_path,
-                                                       monkeypatch):
-    from consensus_specs_tpu.telemetry import report as rpt
-
-    monkeypatch.setenv("CST_COSTMODEL", "1")
-    result = rpt.build_report(
-        repo=tmp_path, history_path=tmp_path / "h.jsonl", snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=20.0, update_history=False)
-    assert result["exit_code"] == 0      # a warning, never a crash/gate
-    assert any("CST_COSTMODEL" in w for w in result["warnings"])
-    assert "## Utilization" in rpt.render_report(result)
 
 
 # --- disabled-path contract --------------------------------------------------
@@ -486,8 +254,7 @@ def test_disabled_capture_and_watermark_are_noops():
     assert costmodel.raw_snapshot() == {
         "kernels": {}, "watermarks": {}, "wm_events": 0,
         "wm_events_dropped": 0}
-    blk = telemetry.bench_block()
-    assert "costmodel" not in blk
+    assert costmodel.block() is None
 
 
 def test_disabled_noop_bound():

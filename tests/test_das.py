@@ -738,197 +738,22 @@ def test_loadgen_schedule_carries_the_recover_lane(monkeypatch):
     assert ex.recover_args in payloads
 
 
-# --- benchwatch wiring -------------------------------------------------------
+# --- the device route at the smallest rung ----------------------------------
 
 
-def _das_block(speedup=25.0, cells=1024, wall=2.5):
-    return {
-        "matrix": {"columns": 128, "blobs": cells // 128,
-                   "cells": cells},
-        "verify_wall_s": wall,
-        "cells_per_s": round(cells / wall, 1),
-        "oracle_wall_s": round(wall * speedup, 2),
-        "oracle_cells_measured": 16,
-        "speedup": speedup,
-        "rung": 1024,
-        "compile_first_s": 30.0,
-        "batch_verdict": True,
-        "isolate": {"bad_cells": 1, "isolated": True},
-        "eval_crosscheck": True,
-    }
-
-
-def test_das_block_schema_validates():
-    from consensus_specs_tpu.telemetry import validate_das_block
-
-    assert validate_das_block(_das_block()) == []
-    bad = _das_block()
-    bad["matrix"]["cells"] = 7
-    assert any("columns * blobs" in p for p in validate_das_block(bad))
-    assert validate_das_block("nope")
-    missing = _das_block()
-    del missing["speedup"]
-    assert any("speedup" in p for p in validate_das_block(missing))
-    noiso = _das_block()
-    noiso["isolate"] = {}
-    assert any("isolate" in p for p in validate_das_block(noiso))
-
-
-def test_das_history_records_and_thresholds(tmp_path):
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.das_records(
-        "das_cell_proof_batch_128x8_verify_wall", _das_block(),
-        platform="cpu", ts=1000.0)
-    by_metric = {r["metric"]: r for r in recs}
-    assert set(by_metric) == {"das::verify_wall@128x8", "das::speedup",
-                              "das::cells_per_s"}
-    for r in recs:
-        assert history.validate_record(r) == [], r
-        assert r["source"] == "das"
-    assert by_metric["das::verify_wall@128x8"]["vs_baseline"] == 25.0
-    assert by_metric["das::speedup"]["value"] == 25.0
-    # malformed blocks degrade to zero records, never raise
-    assert history.das_records("m", {"matrix": "x"}) == []
-    assert history.das_records("m", None) == []
-
-    hist = tmp_path / "h.jsonl"
-    history.append_records(hist, recs)
-    stored, skipped, _ = history.load_history(hist)
-    assert len(stored) == 3 and skipped == 0
-
-    rows = {t["id"]: t for t in report.evaluate_thresholds(stored)}
-    assert rows["das-speedup"]["status"] == "PASS"
-    # cpu-stamped throughput cannot satisfy the TPU-gated row
-    assert rows["das-throughput"]["status"] == "no data"
-    tpu = history.das_records("m", _das_block(wall=0.02),
-                              platform="tpu", ts=2000.0)
-    rows = {t["id"]: t
-            for t in report.evaluate_thresholds(stored + tpu)}
-    assert rows["das-throughput"]["status"] == "PASS"
-    # a sub-2x speedup FAILs the CPU-evaluated acceptance row
-    slow_recs = history.das_records("m", _das_block(speedup=1.5),
-                                    platform="cpu", ts=3000.0)
-    rows = {t["id"]: t
-            for t in report.evaluate_thresholds(stored + slow_recs)}
-    assert rows["das-speedup"]["status"] == "FAIL"
-
-
-def test_das_report_section_renders(tmp_path):
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.das_records(
-        "das_cell_proof_batch_128x8_verify_wall", _das_block(),
-        platform="cpu", ts=1000.0)
-    lines = "\n".join(report.render_das(recs))
-    assert "## DAS (PeerDAS cell-proof sampling)" in lines
-    assert "| 128x8 | 1024 |" in lines
-    assert "Latest speedup over the pure-Python oracle: 25x" in lines
-    empty = "\n".join(report.render_das([]))
-    assert "No das records" in empty
-
-
-def _das_producer_block(producer_speedup=30.0, recover_speedup=12.0):
-    return {
-        "produce_wall_s": 37.0,
-        "produce_first_s": 325.0,
-        "proofs_per_s": 3.5,
-        "du_wall_s": 37.0 * producer_speedup,
-        "du_msms_measured": 2,
-        "producer_speedup": producer_speedup,
-        "parity": True,
-        "recover": {
-            "cells_in": 64,
-            "missing": 64,
-            "wall_s": 34.0,
-            "oracle_wall_s": 34.0 * recover_speedup,
-            "oracle_cosets_measured": 1,
-            "speedup": recover_speedup,
-            "roundtrip": True,
-        },
-    }
-
-
-def test_das_producer_block_schema_validates():
-    from consensus_specs_tpu.telemetry import validate_das_producer_block
-
-    assert validate_das_producer_block(_das_producer_block()) == []
-    assert validate_das_producer_block("nope")
-    bad = _das_producer_block()
-    bad["parity"] = False
-    assert any("parity" in p
-               for p in validate_das_producer_block(bad))
-    bad = _das_producer_block()
-    bad["recover"]["roundtrip"] = False
-    assert any("roundtrip" in p
-               for p in validate_das_producer_block(bad))
-    bad = _das_producer_block()
-    bad["recover"]["cells_in"] = 63
-    assert any("cells_in" in p
-               for p in validate_das_producer_block(bad))
-    missing = _das_producer_block()
-    del missing["producer_speedup"]
-    assert any("producer_speedup" in p
-               for p in validate_das_producer_block(missing))
-
-
-def test_das_producer_history_records_and_thresholds(tmp_path):
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.das_producer_records(
-        "das_fk20_produce_wall", _das_producer_block(),
-        platform="cpu", ts=1000.0)
-    by_metric = {r["metric"]: r for r in recs}
-    assert set(by_metric) == {
-        "das::produce_wall", "das::producer_speedup",
-        "das::proofs_per_s", "das::recover_wall",
-        "das::recover_speedup"}
-    for r in recs:
-        assert history.validate_record(r) == [], r
-        assert r["source"] == "das"
-    assert by_metric["das::produce_wall"]["vs_baseline"] == 30.0
-    assert by_metric["das::produce_wall"]["das_producer"]["parity"] \
-        is True
-    assert by_metric["das::recover_wall"]["vs_baseline"] == 12.0
-    assert by_metric["das::recover_wall"]["das_recover"][
-        "cells_in"] == 64
-    # malformed blocks degrade to zero records, never raise
-    assert history.das_producer_records("m", {"recover": 1}) == []
-    assert history.das_producer_records("m", None) == []
-
-    hist = tmp_path / "h.jsonl"
-    history.append_records(hist, recs)
-    stored, skipped, _ = history.load_history(hist)
-    assert len(stored) == 5 and skipped == 0
-    rows = {t["id"]: t for t in report.evaluate_thresholds(stored)}
-    assert rows["das-producer-speedup"]["status"] == "PASS"
-    assert rows["das-recover-speedup"]["status"] == "PASS"
-    # sub-floor speedups FAIL the CPU-evaluated rows
-    slow_recs = history.das_producer_records(
-        "m", _das_producer_block(producer_speedup=3.0,
-                                 recover_speedup=1.5),
-        platform="cpu", ts=2000.0)
-    rows = {t["id"]: t
-            for t in report.evaluate_thresholds(stored + slow_recs)}
-    assert rows["das-producer-speedup"]["status"] == "FAIL"
-    assert rows["das-recover-speedup"]["status"] == "FAIL"
-
-
-def test_das_producer_report_section_renders():
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.das_producer_records(
-        "das_fk20_produce_wall", _das_producer_block(),
-        platform="cpu", ts=1000.0)
-    lines = "\n".join(report.render_das(recs))
-    assert "FK20 producer: 37 s per blob" in lines
-    assert "30x vs the D_u MSM route" in lines
-    assert "byte-parity OK" in lines
-    assert "Erasure recovery: 34 s" in lines
-    assert "64 surviving cells" in lines
-    assert "12x vs the pure-Python oracle" in lines
-    assert "roundtrip OK" in lines
-    assert "Latest producer throughput:" in lines
+@pytest.mark.parametrize("tampered", [None, 1], ids=["valid", "one_bad_cell"])
+def test_device_route_verdicts_and_isolation(matrix, real_bls, tampered):
+    """The device route on a closed-form matrix (valid by construction):
+    the batch verdict, and the mixed-invalid arc — the RLC batch fails
+    and the per-statement recheck isolates exactly the bad cell."""
+    com, idx, cells, proofs = matrix
+    if tampered is not None:
+        cells = _tamper_cell(cells, tampered)
+    ok, per = das_verify.verify_and_isolate(com, idx, cells, proofs,
+                                            device=True)
+    assert ok is (tampered is None)
+    assert [i for i, v in enumerate(per) if not v] == (
+        [] if tampered is None else [tampered])
 
 
 # --- @slow: device-route end to end ------------------------------------------

@@ -1,6 +1,6 @@
 """Fork-choice subsystem tests — proto-array store vs the phase0 spec
 oracle, the fc_rung ladders, async facades, the serve `head` lane with
-its breaker fallback arc, and the benchwatch wiring.
+its breaker fallback arc, and the synthetic workload the serve lane drives.
 
 Parity contract: every head the device kernels pick must be
 bit-identical to THE SPEC's `get_head` over a Store synthesized from
@@ -507,96 +507,31 @@ def test_loadgen_schedule_carries_the_fc_lane(monkeypatch):
     assert all(s is sentinel for s in ex.stores)
 
 
-# --- benchwatch wiring --------------------------------------------------------
+# --- the synthetic workload: device head vs the spec oracle -----------------
 
 
-def _fc_block(wall=0.002, speedup=500.0, heads=500.0):
-    return {
-        "tree": {"blocks": 256, "validators": 16384, "messages": 8192},
-        "apply_wall_s": 0.001,
-        "head_wall_s": wall,
-        "heads_per_s": heads,
-        "oracle_head_wall_s": 1.0,
-        "oracle_validators_measured": 2048,
-        "speedup": speedup,
-        "rungs": {"blocks": 1024, "validators": 65536, "batch": 1024},
-        "compile_first_s": 2.0,
-        "parity": True,
-    }
+@pytest.mark.parametrize("n_blocks, n_validators, seed", [
+    (48, 256, 53),      # the serve loadgen's fork-choice lane
+    (64, 1024, 29),     # a validator count past the 256 rung
+], ids=["loadgen_lane", "tree_64x1024"])
+def test_synthetic_workload_head_matches_spec_oracle(n_blocks, n_validators,
+                                                     seed):
+    """`forkchoice.synthetic` (the workload the serve lane drives): after
+    each of eight attestation batches the device head is the phase0
+    spec's `get_head`, bit for bit."""
+    import itertools
 
+    from consensus_specs_tpu.forkchoice.synthetic import (
+        attestation_stream,
+        synthetic_store,
+    )
 
-def test_forkchoice_block_schema_validates():
-    from consensus_specs_tpu.telemetry import validate_forkchoice_block
-
-    assert validate_forkchoice_block(_fc_block()) == []
-    assert validate_forkchoice_block("nope")
-    bad = _fc_block()
-    del bad["speedup"]
-    assert any("speedup" in p
-               for p in validate_forkchoice_block(bad))
-    noparity = _fc_block()
-    noparity["parity"] = False
-    assert any("parity" in p
-               for p in validate_forkchoice_block(noparity))
-    norung = _fc_block()
-    norung["rungs"] = {"blocks": 1024}
-    assert any("rungs" in p for p in validate_forkchoice_block(norung))
-
-
-def test_forkchoice_history_records_and_thresholds(tmp_path):
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.forkchoice_records(
-        "forkchoice_lmd_ghost_256x16384_head_wall", _fc_block(),
-        platform="cpu", ts=1000.0)
-    by_metric = {r["metric"]: r for r in recs}
-    assert set(by_metric) == {"forkchoice::head_wall@256x16384",
-                              "forkchoice::speedup",
-                              "forkchoice::heads_per_s"}
-    for r in recs:
-        assert history.validate_record(r) == [], r
-        assert r["source"] == "forkchoice"
-    assert by_metric["forkchoice::head_wall@256x16384"][
-        "vs_baseline"] == 500.0
-    # malformed blocks degrade to zero records, never raise
-    assert history.forkchoice_records("m", {"tree": "x"}) == []
-    assert history.forkchoice_records("m", None) == []
-
-    hist = tmp_path / "h.jsonl"
-    history.append_records(hist, recs)
-    stored, skipped, _ = history.load_history(hist)
-    assert len(stored) == 3 and skipped == 0
-
-    rows = {t["id"]: t for t in report.evaluate_thresholds(stored)}
-    assert rows["fc-speedup"]["status"] == "PASS"
-    # cpu-stamped throughput cannot satisfy the TPU-gated row
-    assert rows["fc-head-throughput"]["status"] == "no data"
-    tpu = history.forkchoice_records("m", _fc_block(),
-                                     platform="tpu", ts=2000.0)
-    rows = {t["id"]: t
-            for t in report.evaluate_thresholds(stored + tpu)}
-    assert rows["fc-head-throughput"]["status"] == "PASS"
-    # a sub-2x speedup FAILs the CPU-evaluated acceptance row
-    slow_recs = history.forkchoice_records(
-        "m", _fc_block(speedup=1.5), platform="cpu", ts=3000.0)
-    rows = {t["id"]: t
-            for t in report.evaluate_thresholds(stored + slow_recs)}
-    assert rows["fc-speedup"]["status"] == "FAIL"
-
-
-def test_forkchoice_report_section_renders():
-    from consensus_specs_tpu.telemetry import history, report
-
-    recs = history.forkchoice_records(
-        "forkchoice_lmd_ghost_256x16384_head_wall", _fc_block(),
-        platform="cpu", ts=1000.0)
-    lines = "\n".join(report.render_forkchoice(recs))
-    assert "## Fork choice (device LMD-GHOST)" in lines
-    assert "| 256x16384 |" in lines
-    assert "Latest head speedup over the phase0 spec oracle: 500x" \
-        in lines
-    empty = "\n".join(report.render_forkchoice([]))
-    assert "No forkchoice records" in empty
+    store, roots = synthetic_store(n_blocks, n_validators, seed=seed)
+    batches = itertools.islice(
+        attestation_stream(roots, n_validators, 64, seed=seed), 8)
+    for k, batch in enumerate(batches):
+        store.apply_attestations(*batch)
+        assert store.get_head() == store.get_head_host(), k
 
 
 # --- @slow: bigger randomized sweep ------------------------------------------

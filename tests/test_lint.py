@@ -160,7 +160,7 @@ def test_real_spec_tree_is_clean_minimal_electra():
     assert lint_spec("electra", "minimal") == []
 
 
-# --- env-knob discipline (benchwatch extension) -----------------------------
+# --- env-knob discipline ---------------------------------------------------
 
 
 def _knob_repo(tmp_path, readme: str, code: str):
@@ -169,40 +169,14 @@ def _knob_repo(tmp_path, readme: str, code: str):
     return tmp_path
 
 
-def test_benchwatch_knob_needs_benchwatch_section_mention(tmp_path):
-    from consensus_specs_tpu.lint import lint_env_knobs
-
-    readme = ("## Benchwatch\n\nno knob mention here\n\n"
-              "## Environment knobs\n\n"
-              "| `CST_BENCHWATCH_FOO` | unset | a knob |\n")
-    # knob name assembled at runtime so THIS file's source never
-    # pattern-matches the tree-wide env-read scan
-    knob = "CST_" + "BENCHWATCH_FOO"
-    repo = _knob_repo(tmp_path, readme,
-                      "import os\nX = os.environ.get(%r)\n" % knob)
-    found = lint_env_knobs(repo)
-    assert len(found) == 1
-    assert "Benchwatch" in found[0] and knob in found[0]
-
-
-def test_benchwatch_knob_mention_with_value_suffix_passes(tmp_path):
-    from consensus_specs_tpu.lint import lint_env_knobs
-
-    readme = ("## Benchwatch\n\nset `CST_BENCHWATCH_FOO=1` to enable\n\n"
-              "## Environment knobs\n\n"
-              "| `CST_BENCHWATCH_FOO` | unset | a knob |\n")
-    knob = "CST_" + "BENCHWATCH_FOO"
-    repo = _knob_repo(tmp_path, readme,
-                      "import os\nX = os.environ.get(%r)\n" % knob)
-    assert lint_env_knobs(repo) == []
-
-
 def test_serve_knob_needs_serving_section_mention(tmp_path):
     from consensus_specs_tpu.lint import lint_env_knobs
 
     readme = ("## Serving\n\nno knob mention here\n\n"
               "## Environment knobs\n\n"
               "| `CST_SERVE_FOO` | unset | a knob |\n")
+    # knob name assembled at runtime so THIS file's source never
+    # pattern-matches the tree-wide env-read scan
     knob = "CST_" + "SERVE_FOO"
     repo = _knob_repo(tmp_path, readme,
                       "import os\nX = os.environ.get(%r)\n" % knob)
@@ -254,7 +228,7 @@ def test_undocumented_knob_still_caught(tmp_path):
     from consensus_specs_tpu.lint import lint_env_knobs
 
     knob = "CST_" + "NEW_KNOB"
-    repo = _knob_repo(tmp_path, "## Benchwatch\n",
+    repo = _knob_repo(tmp_path, "## Serving\n",
                       "import os\nX = os.environ[%r]\n" % knob)
     found = lint_env_knobs(repo)
     assert len(found) == 1 and knob in found[0]

@@ -1,7 +1,6 @@
 """Mesh-resilient sharded verification (`resilience/mesh.py`):
 shard-loss re-bucket recovery, the half-open re-admission state
-machine, degraded-mode lane counters, and the `mesh` benchwatch record
-kind.
+machine, degraded-mode lane counters, and the `mesh` block.
 
 State-machine and counter contracts run against a STUB dispatcher
 (the tests/test_serve.py pattern) with an injectable clock, so tier-1
@@ -26,7 +25,6 @@ from consensus_specs_tpu.resilience.mesh import (
     is_device_failure,
 )
 from consensus_specs_tpu.serve.futures import DeviceFuture
-from consensus_specs_tpu.telemetry import history as benchwatch
 from consensus_specs_tpu.telemetry import validate_mesh_block
 
 
@@ -200,76 +198,50 @@ def test_block_schema_and_history_round_trip():
     block.update({"wrong_results": 0, "dropped_statements": 0,
                   "checked_statements": 2, "readmitted": False})
     assert validate_mesh_block(block) == []
-    records = benchwatch.mesh_records("serve_sustained_load", block,
-                                      platform="cpu", ts=123.0)
-    by_metric = {r["metric"]: r for r in records}
-    assert set(by_metric) == {
-        "mesh::recovery_latency_s", "mesh::recovered",
-        "mesh::lost_statements", "mesh::wrong_results",
-        "mesh::degraded_lanes", "mesh::device_lost_events",
-        "mesh::readmissions"}
-    assert by_metric["mesh::recovered"]["value"] == 1.0
-    for rec in records:
-        assert benchwatch.validate_record(rec) == [], rec
-        assert rec["source"] == "mesh"
-    assert by_metric["mesh::lost_statements"]["value"] == 0
-    assert by_metric["mesh::device_lost_events"]["value"] == 1
-    compact = by_metric["mesh::recovery_latency_s"]["mesh"]
-    assert compact["devices"] == 4 and compact["redispatches"] == 1
+    assert block["recovered"] is True
+    assert block["lost_statements"] == 0
+    assert block["device_lost_events"] == 1
+    assert block["devices"] == 4 and block["redispatches"] == 1
 
 
 def test_skipped_and_malformed_mesh_blocks_yield_no_records():
-    assert benchwatch.mesh_records("m", None) == []
-    assert benchwatch.mesh_records("m", {"skipped": "1 device(s)"}) == []
-    assert benchwatch.mesh_records("m", {"devices": "eight"}) == []
     assert validate_mesh_block({"skipped": "1 device(s)"}) == []
     assert validate_mesh_block(None) == []
     assert validate_mesh_block({"devices": True})  # bool is not an int
 
 
-def test_mesh_threshold_rows():
-    from consensus_specs_tpu.telemetry import report
+@pytest.mark.parametrize("when", ["on_the_lost_dispatch",
+                                  "while_degraded"])
+def test_invalid_statement_still_rejected_across_shard_loss(when):
+    """The chaos mesh segment's safety check, on a dispatcher whose
+    verdict is the statements' own: an invalid statement in the batch
+    that loses a shard, or in one sent while degraded, is still refused
+    on the survivors; nothing is dropped; the probe re-admits the
+    mesh."""
+    calls = []
+    lose = [True]
 
-    rows = {t["id"]: t for t in report.THRESHOLDS}
-    assert rows["mesh-recovery"]["op"] == "<"
-    assert rows["mesh-recovery"]["target"] == 60.0
-    assert not rows["mesh-recovery"]["tpu_only"]
-    assert rows["mesh-lost-statements"]["target"] == 1.0
-    assert rows["mesh-wrong-results"]["target"] == 1.0
-    # a clean mesh round PASSes both rows
-    recs = benchwatch.mesh_records("m", {
-        "devices": 8, "degraded_lanes": 0, "max_degraded_lanes": 1,
-        "device_lost_events": 1, "readmissions": 1, "retrips": 0,
-        "redispatches": 1, "recoveries": 1, "recovery_latency_s": 2.5,
-        "verified_statements": 20, "lost_statements": 0,
-        "wrong_results": 0, "checked_statements": 21,
-        "readmitted": True, "recovered": True}, platform="cpu", ts=5.0)
-    evaluated = {t["id"]: t for t in report.evaluate_thresholds(recs)}
-    assert evaluated["mesh-recovered"]["status"] == "PASS"
-    assert evaluated["mesh-recovery"]["status"] == "PASS"
-    assert evaluated["mesh-lost-statements"]["status"] == "PASS"
-    assert evaluated["mesh-wrong-results"]["status"] == "PASS"
-    # a lossy round FAILs the zero-loss gate — and a wrong-answer round
-    # FAILs its own row even when zero statements were dropped (the two
-    # rows are deliberately separate: same-timestamp records would tie
-    # in a single row's latest-wins pick)
-    lossy = benchwatch.mesh_records("m", {
-        "devices": 8, "degraded_lanes": 8, "max_degraded_lanes": 8,
-        "device_lost_events": 8, "readmissions": 0, "retrips": 0,
-        "redispatches": 7, "recoveries": 0, "recovery_latency_s": None,
-        "verified_statements": 0, "lost_statements": 4,
-        "wrong_results": 2, "checked_statements": 0,
-        "readmitted": False, "recovered": False},
-        platform="cpu", ts=6.0)
-    evaluated = {t["id"]: t
-                 for t in report.evaluate_thresholds(recs + lossy)}
-    assert evaluated["mesh-lost-statements"]["status"] == "FAIL"
-    assert evaluated["mesh-wrong-results"]["status"] == "FAIL"
-    # the unrecovered round's latency record is null (invisible to the
-    # numeric mesh-recovery row, which keeps the OLD round's PASS) —
-    # the 0/1 recovered record is what turns the dashboard red
-    assert evaluated["mesh-recovery"]["status"] == "PASS"
-    assert evaluated["mesh-recovered"]["status"] == "FAIL"
+    def dispatch(tasks, rng, ids):
+        calls.append(tuple(ids))
+        if lose[0] and len(ids) == 4:
+            lose[0] = False
+            raise MeshDeviceLost("dispatch", f"rlc_sharded@{len(ids)}x8",
+                                 "device_loss")
+        return DeviceFuture.settled("bad" not in tasks)
+
+    clock = FakeClock()
+    mv = MeshVerifier(n_devices=4, readmit_cooldown_s=1.0, clock=clock,
+                      dispatch_fn=dispatch, available_fn=lambda: 4)
+    good = ["a", "b", "c"]
+    if when == "while_degraded":
+        assert mv.verify(good) is True
+    assert mv.verify(good + ["bad"]) is False
+    assert calls[-1] == (0, 1, 2) and mv.state.degraded()
+    assert mv.verify(good) is True
+    assert mv.lost_statements == 0 and mv.state.lost_events == 1
+    clock.t = 1.5
+    assert mv.verify(good + ["bad"]) is False
+    assert calls[-1] == (0, 1, 2, 3) and not mv.state.degraded()
 
 
 # --- serve executor wiring ---------------------------------------------------

@@ -5,8 +5,7 @@ Pins the live-monitoring contracts the pod round leans on: the rule
 engine evaluated on a FAKE clock (windows, breach→clear hysteresis,
 flap suppression), malformed `CST_SLO_RULES` rejected with a counted
 warning instead of a dead round, the disabled path a true no-op, the
-exposition text round-tripping through its own strict parser (the same
-line-by-line validation bench_smoke applies to the mid-round scrape),
+exposition text round-tripping through its own strict parser,
 and the reqtrace live window staying a fixed-size ring so the rolling
 summary is O(window) under sustained load.
 """
@@ -330,37 +329,3 @@ def test_live_window_is_bounded_and_summary_reads_the_tail():
     assert total == cap + 128
     assert by_kind["old"] == cap + 64 and by_kind["new"] == 64
     assert by_outcome == {"ok": cap + 128}
-
-
-# --- slo::* history mining ---------------------------------------------------
-
-
-def test_slo_history_records_and_chaos_clean_round_gate():
-    from consensus_specs_tpu.telemetry import history as benchwatch
-
-    slo = {"breaches": 2, "ticks": 9, "clean": False,
-           "rules": [{"name": "q", "metric": "serve.queue_depth",
-                      "breaches": 2, "clears": 1, "breaching": False,
-                      "worst_margin": 12.5, "last_value": 3.0}]}
-    recs = {r["metric"]: r for r in benchwatch.slo_records("m", slo)}
-    assert recs["slo::breaches"]["value"] == 2
-    assert recs["slo::breaches"]["slo"]["ticks"] == 9
-    assert recs["slo::breaches@q"]["value"] == 2
-    assert recs["slo::worst_margin@q"]["value"] == 12.5
-    assert recs["slo::clean_round"]["value"] == 0.0
-    for r in recs.values():
-        assert r["source"] == "slo" and not benchwatch.validate_record(r)
-    # a chaos round breaches BY DESIGN: no clean_round record
-    assert "slo::clean_round" not in {
-        r["metric"] for r in benchwatch.slo_records("m", slo, chaos=True)}
-    # bench_serve hoists "resilience" to the metric line's top level —
-    # the emission path must still see the round as chaos
-    line = {"metric": "m", "value": 1.0,
-            "serve": {"verifies_per_s": 1.0, "slo": slo},
-            "resilience": {"chaos": True}}
-    names = {r["metric"] for r in benchwatch.emission_records(line, ts=1.0)}
-    assert "slo::breaches@q" in names
-    assert "slo::clean_round" not in names
-    # malformed blocks: zero records, never a crash
-    assert benchwatch.slo_records("m", None) == []
-    assert benchwatch.slo_records("m", {"breaches": "two"}) == []

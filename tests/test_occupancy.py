@@ -1,15 +1,14 @@
 """Device-occupancy ledger contract tests
 (`consensus_specs_tpu/telemetry/occupancy.py`).
 
-Pins the pipeline-occupancy contracts the serve smoke and the pod
-round lean on: the interval arithmetic (union-merge across overlapping
-multi-device dispatches), the EXACT bubble partition (busy + the four
+Pins the pipeline-occupancy contracts a serve round leans on: the
+interval arithmetic (union-merge across overlapping multi-device
+dispatches), the EXACT bubble partition (busy + the four
 causes sum to the measured wall to 1e-6 relative — the same contiguity
 contract as reqtrace's latency components), the overlap score telling a
 serialized depth-1 pipeline from a hidden depth-3 one, the disabled
-path a true no-op, the serve-block schema
-(`export.validate_occupancy_block`), and the `pipeline::*`
-history/report/threshold round-trips.
+path a true no-op, and the serve-block schema
+(`export.validate_occupancy_block`).
 """
 
 from __future__ import annotations
@@ -20,7 +19,6 @@ import pytest
 
 from consensus_specs_tpu import telemetry
 from consensus_specs_tpu.telemetry import core, occupancy
-from consensus_specs_tpu.telemetry import history as benchwatch
 from consensus_specs_tpu.telemetry.export import validate_occupancy_block
 
 # busy + bubbles must sum to the wall within this RELATIVE tolerance
@@ -338,70 +336,3 @@ def test_snapshot_carries_occupancy_subobject():
     snap = telemetry.snapshot()
     occ = snap["occupancy"]
     assert occ["enabled"] and occ["events"] == 1
-
-
-# --- history / report / threshold round-trips --------------------------------
-
-
-def _occ_block():
-    _prep(0.0, 1.0)
-    _busy(1.0, 9.0)
-    _settle(9.0, 9.2)
-    return occupancy.block(window=(0.0, 10.0), depth=2)
-
-
-def test_pipeline_records_mined_from_serve_block():
-    serve = {"verifies_per_s": 10.0, "p50_ms": 1.0, "p99_ms": 2.0,
-             "steady": True, "occupancy": _occ_block()}
-    recs = benchwatch.serve_records("serve_sustained_load", serve,
-                                    platform="cpu")
-    by_metric = {r["metric"]: r for r in recs}
-    rec = by_metric["pipeline::busy_frac"]
-    assert rec["source"] == "pipeline" and rec["value"] == 0.8
-    assert rec["occupancy"]["depth"] == 2
-    assert benchwatch.validate_record(rec) == []
-    for cause in occupancy.BUBBLE_CAUSES:
-        assert f"pipeline::bubble@{cause}" in by_metric
-    assert by_metric["pipeline::overlap_score"]["value"] == 0.0
-    # malformed blocks yield nothing, never an exception
-    assert benchwatch.occupancy_records("m", None) == []
-    assert benchwatch.occupancy_records("m", {"busy_frac": "hi"}) == []
-
-
-def test_occupancy_history_report_and_threshold(tmp_path, monkeypatch):
-    hist = tmp_path / "hist.jsonl"
-    monkeypatch.setenv("CST_BENCHWATCH_HISTORY", str(hist))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    serve = {"metric": "serve_sustained_load", "value": 10.0,
-             "unit": "verifies/s",
-             "serve": {"verifies_per_s": 10.0, "p50_ms": 1.0,
-                       "p99_ms": 2.0, "steady": True,
-                       "occupancy": _occ_block()}}
-    n = benchwatch.append_emission(serve, ts=time.time())
-    assert n >= 6                      # serve:: + pipeline:: records
-    records, skipped, warns = benchwatch.load_history(hist)
-    assert not skipped and not warns
-    from consensus_specs_tpu.telemetry import report as bw_report
-
-    result = bw_report.build_report(
-        repo=tmp_path, history_path=hist, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    text = bw_report.render_report(result)
-    assert "Pipeline occupancy" in text
-    assert "host_prep" in text and "busy" in text
-    rows = {t["id"]: t for t in result["thresholds"]}
-    # TPU-gated row: CPU records read 'no data'
-    assert rows["serve-occupancy"]["status"] == "no data"
-    # a TPU-stamped record evaluates (0.8 >= 0.7 -> PASS)
-    tpu = benchwatch.occupancy_records(
-        "serve_sustained_load", _occ_block(), platform="tpu",
-        ts=time.time())
-    benchwatch.append_records(hist, tpu)
-    result = bw_report.build_report(
-        repo=tmp_path, history_path=hist, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    rows = {t["id"]: t for t in result["thresholds"]}
-    assert rows["serve-occupancy"]["status"] == "PASS", \
-        rows["serve-occupancy"]

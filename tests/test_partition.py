@@ -410,85 +410,3 @@ def test_sharded_msm_matches_single_chip_and_oracle():
     one_dev = g1_multi_exp_sharded(pts[:2], ks[:2], n_devices=1)
     assert pc.g1.to_affine(one_dev) == pc.g1.to_affine(
         g1_multi_exp_device(pts[:2], ks[:2]))
-
-
-# --- scaling block / record round-trip (host-only) ---------------------------
-
-
-def test_scaling_block_schema_and_records():
-    from consensus_specs_tpu.telemetry import (
-        history,
-        validate_scaling_block,
-    )
-
-    block = {"n_devices": 8, "ok_8m": True, "rungs": [
-        {"n_validators": 1 << 21, "n_devices": 8, "wall_s": 0.5,
-         "per_chip_vps": 500000.0, "total_vps": 4e6,
-         "single_chip_wall_s": 0.4, "single_chip_vps": 650000.0,
-         "efficiency": 0.77},
-        {"n_validators": 1 << 23, "n_devices": 8, "wall_s": 1.9,
-         "per_chip_vps": 550000.0, "total_vps": 4.4e6,
-         "single_chip_wall_s": 1.5, "single_chip_vps": 700000.0,
-         "efficiency": 0.786},
-    ]}
-    assert validate_scaling_block(block) == []
-    assert validate_scaling_block({"rungs": []})
-    assert validate_scaling_block(
-        {"n_devices": 8, "rungs": [{"n_validators": 0}]})
-
-    recs = history.scaling_records("flagship_scaling", block,
-                                   platform="tpu", ts=1.0)
-    by_metric = {r["metric"]: r for r in recs}
-    assert f"scaling::flagship@{1 << 21}" in by_metric
-    assert f"scaling::efficiency@{1 << 23}" in by_metric
-    # the summary record carries the LARGEST rung
-    summary = by_metric["scaling::efficiency"]
-    assert summary["value"] == 0.786
-    assert summary["scaling"]["n_validators"] == 1 << 23
-    assert by_metric["scaling::flagship_8m_ok"]["value"] == 1.0
-    for r in recs:
-        assert not history.validate_record(r), r
-        assert r["source"] == "scaling", r
-    # malformed blocks yield zero records, never an exception
-    assert history.scaling_records("m", None) == []
-    assert history.scaling_records("m", {"rungs": "nope"}) == []
-
-
-def test_scaling_threshold_rows_and_report_section(tmp_path):
-    from consensus_specs_tpu.telemetry import history, report
-
-    block = {"n_devices": 8, "ok_8m": False, "rungs": [
-        {"n_validators": 1 << 21, "n_devices": 8, "wall_s": 0.5,
-         "per_chip_vps": 500000.0, "total_vps": 4e6,
-         "single_chip_wall_s": 0.4, "single_chip_vps": 800000.0,
-         "efficiency": 0.625}]}
-    recs = history.scaling_records("flagship_scaling", block,
-                                   platform="tpu", ts=10.0)
-    hist = tmp_path / "hist.jsonl"
-    history.append_records(hist, recs)
-    result = report.build_report(
-        repo=tmp_path, history_path=hist, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    rows = {t["id"]: t for t in result["thresholds"]}
-    # 62.5% retention FAILs the 70% gate; the failed 8M rung FAILs too
-    assert rows["scaling-efficiency"]["status"] == "FAIL"
-    assert rows["scaling-efficiency"]["observed"] == 0.625
-    assert rows["flagship-8m"]["status"] == "FAIL"
-    text = report.render_report(result)
-    assert "## Scaling (mesh-sharded flagship)" in text
-    assert f"| {1 << 21} | 8 |" in text
-    assert "ATTEMPTED AND FAILED" in text
-    # a CPU-stamped record must NOT satisfy the TPU-gated rows
-    cpu_recs = history.scaling_records("flagship_scaling",
-                                       dict(block, ok_8m=True),
-                                       platform="cpu", ts=20.0)
-    hist2 = tmp_path / "hist2.jsonl"
-    history.append_records(hist2, cpu_recs)
-    result = report.build_report(
-        repo=tmp_path, history_path=hist2, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    rows = {t["id"]: t for t in result["thresholds"]}
-    assert rows["scaling-efficiency"]["status"] == "no data"
-    assert rows["flagship-8m"]["status"] == "no data"

@@ -1,8 +1,8 @@
 """Request-scoped tracing (`telemetry/reqtrace.py`) + its serve-pipeline
 wiring: context lifecycle across every outcome, batch lineage,
 attribution arithmetic, Chrome-trace flow events, the disabled no-op
-bound, the serve-block `latency_attribution` schema, the `latency::*`
-history record kind, the live `status()` contract, and the analyzer's
+bound, the serve-block `latency_attribution` schema, the live
+`status()` contract, and the analyzer's
 `reqtrace-uncovered-submit` rule.
 
 Executor tests run against stubbed dispatchers (the test_serve.py
@@ -24,7 +24,6 @@ from consensus_specs_tpu.telemetry import (
     validate_latency_attribution,
     validate_serve_block,
 )
-from consensus_specs_tpu.telemetry import history as benchwatch
 
 COMPONENT_SUM_EPS = 1e-6      # components are contiguous: exact to fp
 
@@ -423,81 +422,6 @@ def test_serve_block_latency_source_contract():
     block["latency_source"] = "sundial"
     problems = validate_serve_block(block)
     assert any("latency_source" in p for p in problems), problems
-
-
-# --- latency::* history record kind ------------------------------------------
-
-
-def _serve_line():
-    return {"metric": "serve_sustained_load", "value": 10.0,
-            "unit": "verifies/s",
-            "serve": {
-                "verifies_per_s": 10.0, "p50_ms": 1.0, "p99_ms": 2.0,
-                "latency_source": "reqtrace",
-                "steady": True, "windows": [10.0, 10.0, 10.0],
-                "submitted": 5, "settled": 5, "failed": 0,
-                "queue_depth": {"max": 1, "hist": {"1": 5}},
-                "mode": "closed",
-                "latency_attribution": _good_attribution(),
-            }}
-
-
-def test_latency_records_mined_from_serve_block():
-    recs = benchwatch.serve_records(
-        "serve_sustained_load", _serve_line()["serve"], platform="cpu")
-    by_metric = {r["metric"]: r for r in recs}
-    assert "latency::p99_ms@verify" in by_metric, sorted(by_metric)
-    lrec = by_metric["latency::p99_ms@verify"]
-    assert lrec["source"] == "latency" and lrec["value"] == 4.0
-    assert lrec["latency"]["p99_components_ms"]["queue_wait"] == 1.0
-    assert benchwatch.validate_record(lrec) == []
-    qrec = by_metric["latency::p99_queue_frac"]
-    assert qrec["value"] == 0.3 and qrec["latency"]["worst"]
-    # the compacted serve block names its latency basis
-    assert by_metric["serve::verifies_per_s"]["serve"][
-        "latency_source"] == "reqtrace"
-
-
-def test_latency_records_malformed_yield_nothing():
-    assert benchwatch.latency_records("m", None) == []
-    assert benchwatch.latency_records("m", {"kinds": "x"}) == []
-    assert benchwatch.latency_records(
-        "m", {"kinds": {"verify": {"p99_ms": "slow"}}}) == []
-
-
-def test_latency_history_round_trip_and_report(tmp_path, monkeypatch):
-    hist = tmp_path / "hist.jsonl"
-    monkeypatch.setenv("CST_BENCHWATCH_HISTORY", str(hist))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    n = benchwatch.append_emission(_serve_line(), ts=time.time())
-    assert n >= 6       # bench_emit + 3 serve:: + 2 latency:: records
-    records, skipped, warns = benchwatch.load_history(hist)
-    assert not skipped and not warns
-    from consensus_specs_tpu.telemetry import report as bw_report
-
-    result = bw_report.build_report(
-        repo=tmp_path, history_path=hist, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    text = bw_report.render_report(result)
-    assert "## Tail latency (request tracing)" in text
-    assert "`verify`" in text and "Worst exemplar traces:" in text
-    rows = {t["id"]: t for t in result["thresholds"]}
-    # TPU-gated advisory row: CPU records read 'no data'
-    assert rows["serve-p99-queue-frac"]["status"] == "no data"
-    # a TPU-stamped record evaluates (0.3 < 0.5 -> PASS)
-    tpu = benchwatch.latency_records(
-        "serve_sustained_load",
-        _serve_line()["serve"]["latency_attribution"], platform="tpu",
-        ts=time.time())
-    benchwatch.append_records(hist, tpu)
-    result = bw_report.build_report(
-        repo=tmp_path, history_path=hist, snapshots=[],
-        durations_path=None, top_n=5, strict=False,
-        max_regress_pct=0.0, update_history=False)
-    rows = {t["id"]: t for t in result["thresholds"]}
-    assert rows["serve-p99-queue-frac"]["status"] == "PASS", \
-        rows["serve-p99-queue-frac"]
 
 
 # --- live status -------------------------------------------------------------

@@ -1,8 +1,8 @@
 """The resilience layer (`consensus_specs_tpu/resilience/`):
 deterministic fault injection at the sanctioned seams, retry/breaker/
 degraded-mode recovery in the serve executor, deadline shedding, typed
-bounded futures waits, self-healing Merkle state, and the `resilience`
-benchwatch record kind.
+bounded futures waits, self-healing Merkle state, the chaos round and
+the `resilience` block schema.
 
 Executor-layer tests run against stubbed dispatchers (the
 tests/test_serve.py pattern) so blast-radius/retry/breaker contracts
@@ -41,7 +41,6 @@ from consensus_specs_tpu.serve.futures import (
     value_future,
 )
 from consensus_specs_tpu.telemetry import validate_resilience_block
-from consensus_specs_tpu.telemetry import history as benchwatch
 
 
 @pytest.fixture(autouse=True)
@@ -466,9 +465,8 @@ def test_oracle_fallback_verify_bit_identical_to_device():
     """Breaker-open degraded mode must return exactly the device
     verdicts: valid and invalid statements, via the real RLC kernel vs
     the pure-Python oracle.  `slow` like every RLC-compiling test
-    (tier-1 pins the sha256/fr fallback identities below; the CI
-    chaos-smoke exercises the verify fallback against live traffic on
-    every run)."""
+    (tier-1 pins the chaos fault plan's verify fallback at four
+    statements, and the sha256/fr fallback identities, below)."""
     from consensus_specs_tpu.ops.bls_batch import batch_verify
     from consensus_specs_tpu.serve.executor import _oracle_compute
     from consensus_specs_tpu.serve.loadgen import build_statement_pool
@@ -478,6 +476,95 @@ def test_oracle_fallback_verify_bit_identical_to_device():
     bad = (pk, b"\x13" * 32, sig)          # signature over another msg
     for task in (*good, bad):
         assert _oracle_compute("verify", task) == batch_verify([task])
+
+
+@pytest.mark.parametrize("invalid", [False, True],
+                         ids=["all_valid", "one_invalid"])
+def test_chaos_fault_plan_leaves_no_wrong_or_dropped_answer(invalid,
+                                                            monkeypatch):
+    """The chaos round's canned plan (dispatch failures into the RLC
+    kernel, at its real dispatch seam) against the recovery policies the
+    round runs with, at four wire-format statements: every answer is the
+    oracle's and none is dropped, while the breaker trips onto the
+    oracle fallback and re-closes once the faults stop.  Parse, batch
+    preparation and dispatch are the program's; the kernel is an exact
+    stand-in that refuses a batch holding the invalid statement's
+    message (the kernel itself is checked against the oracle by
+    `tests/test_chip_smoke.py`)."""
+    import jax.numpy as jnp
+
+    from consensus_specs_tpu.ops import bls_batch
+    from consensus_specs_tpu.ops.bls import ciphersuite as cs
+    from consensus_specs_tpu.ops.bls_batch.h2c_jax import msgs_to_words
+    from consensus_specs_tpu.resilience.chaos import (
+        CHAOS_BREAKER,
+        CHAOS_RETRY,
+        DEFAULT_CHAOS_SPEC,
+    )
+    from consensus_specs_tpu.serve.loadgen import build_statement_pool
+
+    wire = [(cs.g1_to_bytes(pk), msg, cs.g2_to_bytes(sig))
+            for pk, msg, sig in build_statement_pool(4, 2, seed_base=8800)]
+    if invalid:
+        wire[2] = (wire[2][0], wire[2][1], wire[3][2])
+    want = [cs.FastAggregateVerify([pk], msg, sig) for pk, msg, sig in wire]
+    assert want.count(False) == int(invalid)
+    bad_words = [msgs_to_words([m])[0]
+                 for (_, m, _), ok in zip(wire, want) if not ok]
+
+    def kernel(*args):
+        (words,) = [np.asarray(a) for a in args
+                    if np.asarray(a).dtype == np.uint32
+                    and np.asarray(a).shape[-1:] == (8,)]
+        return jnp.asarray(not any((words == w).all(axis=-1).any()
+                                   for w in bad_words))
+
+    monkeypatch.setattr(bls_batch, "_rlc_kernel_h2c", lambda batch: kernel)
+    clock = [0.0]
+    breakers = BreakerRegistry(**CHAOS_BREAKER, clock=lambda: clock[0])
+    ex = ServeExecutor(max_batch=4, retry=RetryPolicy(**CHAOS_RETRY),
+                       breakers=breakers)
+    faults.install(DEFAULT_CHAOS_SPEC)
+    try:
+        for _ in range(4):
+            futs = [ex.submit_fast_aggregate_verify([pk], msg, sig)
+                    for pk, msg, sig in wire]
+            ex.drain()
+            assert [f.result() for f in futs] == want
+            clock[0] += CHAOS_BREAKER["cooldown_s"] + 0.1
+    finally:
+        injected = faults.injections()
+        faults.clear()
+    assert len(injected) == 4
+    assert {i["site"] for i in injected} == {"dispatch"}
+    assert all(i["key"].startswith("rlc_h2c@") for i in injected)
+    st = ex.stats()
+    assert st["failed"] == 0 and st["settled"] == st["submitted"] == 16
+    assert st["fallbacks"] >= 4
+    tos = [t["to"] for t in breakers.transitions]
+    assert "open" in tos and tos[-1] == "closed"
+
+
+def test_flagship_breaker_arc_keeps_every_verdict(monkeypatch):
+    """The chaos round's block-executor segment: a dispatch fault trips
+    the settle breaker onto the pure-Python spec oracle, an OPEN breaker
+    settles on the oracle alone, and the half-open probe re-closes;
+    every settle answers as the statements are (valid), and both
+    degraded settles are counted.  The kernel is an exact stand-in
+    (every statement of the segment is valid)."""
+    import jax.numpy as jnp
+
+    from consensus_specs_tpu.ops import bls_batch
+    from consensus_specs_tpu.resilience.chaos import _flagship_segment
+
+    monkeypatch.setattr(bls_batch, "_rlc_kernel_h2c",
+                        lambda batch: lambda *args: jnp.asarray(True))
+    block = _flagship_segment()
+    assert block["wrong_results"] == 0 and block["checked_settles"] == 4
+    assert block["degraded_steps"] == 2
+    assert block["breaker"]["trips"] == 1
+    assert block["recovered"]
+    assert not faults.active()
 
 
 def test_oracle_fallback_sha256_and_fr_bit_identical():
@@ -667,71 +754,6 @@ def test_validate_resilience_block_flags_problems():
     assert validate_resilience_block(bad)
     bad = dict(good, heal={"diverged": True, "recovery_s": None})
     assert any("recovery_s" in p for p in validate_resilience_block(bad))
-
-
-def test_resilience_history_records_and_threshold_rows(tmp_path):
-    """The record kind round-trips through the store and feeds the
-    chaos-recovery / chaos-correctness threshold rows."""
-    from consensus_specs_tpu.telemetry.report import evaluate_thresholds
-
-    res = _canned_resilience_block()
-    recs = benchwatch.resilience_records(
-        "serve_sustained_load", res, platform="cpu", ts=1000.0)
-    by_metric = {r["metric"]: r for r in recs}
-    assert by_metric["resilience::recovery_latency_s"]["value"] == 7.4
-    assert by_metric["resilience::wrong_results"]["value"] == 0
-    assert by_metric["resilience::breaker_transitions"]["value"] == 3
-    assert by_metric["resilience::merkle_heal_s"]["value"] == 0.02
-    compact = by_metric["resilience::recovery_latency_s"]["resilience"]
-    assert compact["breaker_trips"] == 1 and compact["recovered"]
-    for r in recs:
-        assert r["source"] == "resilience"
-        assert not benchwatch.validate_record(r), r
-    store = tmp_path / "hist.jsonl"
-    assert benchwatch.append_records(store, recs) == len(recs)
-    loaded, skipped, warns = benchwatch.load_history(store)
-    assert len(loaded) == len(recs) and not skipped and not warns
-
-    rows = {t["id"]: t for t in evaluate_thresholds(loaded)}
-    assert rows["chaos-recovery"]["status"] == "PASS"
-    assert rows["chaos-recovery"]["observed"] == 7.4
-    assert rows["chaos-recovered"]["status"] == "PASS"
-    assert rows["chaos-correctness"]["status"] == "PASS"
-    # an unrecovered round has a null latency (no fallback to an older
-    # PASS — the chaos-recovered row carries the failure, latest-wins)
-    unrecovered = benchwatch.resilience_records(
-        "m", dict(res, recovery_latency_s=None, recovered=False),
-        ts=2000.0)
-    rows = {t["id"]: t for t in evaluate_thresholds(unrecovered)}
-    assert rows["chaos-recovery"]["status"] == "no data"
-    assert rows["chaos-recovered"]["status"] == "FAIL"
-    # ... and it FAILs even with the older successful round in store
-    rows = {t["id"]: t for t in evaluate_thresholds(loaded + unrecovered)}
-    assert rows["chaos-recovered"]["status"] == "FAIL"
-    # a wrong answer fails the correctness gate
-    rows = {t["id"]: t for t in evaluate_thresholds(
-        benchwatch.resilience_records("m", dict(res, wrong_results=3)))}
-    assert rows["chaos-correctness"]["status"] == "FAIL"
-
-
-def test_malformed_resilience_block_yields_zero_records():
-    assert benchwatch.resilience_records("m", None) == []
-    assert benchwatch.resilience_records("m", {"nope": 1}) == []
-    assert benchwatch.resilience_records("m", "text") == []
-
-
-def test_report_renders_resilience_section():
-    from consensus_specs_tpu.telemetry.report import render_resilience
-
-    recs = benchwatch.resilience_records(
-        "serve_sustained_load", _canned_resilience_block(),
-        platform="cpu", ts=1000.0)
-    text = "\n".join(render_resilience(recs))
-    assert "## Resilience (chaos rounds)" in text
-    assert "`resilience::recovery_latency_s`" in text
-    assert "recovered" in text and "dispatch: 4" in text
-    empty = "\n".join(render_resilience([]))
-    assert "No resilience records" in empty
 
 
 # --- the analyzer rule -------------------------------------------------------

@@ -1,6 +1,6 @@
 """The serving subsystem (`consensus_specs_tpu/serve/`): deferred-result
-futures, the batching executor, the sustained-load generator, the bench
-`"serve"` block schema, and the `serve` benchwatch record kind.
+futures, the batching executor, the sustained-load generator, and the
+`"serve"` block schema.
 
 Executor tests run against stubbed dispatchers (no jax, no kernels) so
 the pipeline/batching/poisoning contracts are pinned cheaply; one
@@ -12,9 +12,7 @@ propagation) ride the same futures contract.
 
 from __future__ import annotations
 
-import json
 import os
-import time
 from types import SimpleNamespace
 
 import numpy as np
@@ -28,7 +26,6 @@ from consensus_specs_tpu.serve import (
 )
 from consensus_specs_tpu.serve.executor import ServeExecutor, _depth_bucket
 from consensus_specs_tpu.telemetry import validate_serve_block
-from consensus_specs_tpu.telemetry import history as benchwatch
 
 
 # --- DeviceFuture ------------------------------------------------------------
@@ -583,104 +580,6 @@ def test_validate_serve_block_null_latencies_ok():
 
 def test_validate_serve_block_non_dict():
     assert validate_serve_block([1, 2]) != []
-
-
-# --- benchwatch: the serve record kind ---------------------------------------
-
-
-def _serve_metric_line():
-    return {"metric": "serve_sustained_load", "value": 123.4,
-            "unit": "verifies/s", "vs_baseline": 41.0,
-            "serve": dict(_good_block(), rechecks=0, batches=25,
-                          inflight_max=3, window_s=2.0, duration_s=6.0,
-                          rate_multiple=0.0, max_batch=8, depth=2)}
-
-
-def test_serve_records_split_throughput_and_latency():
-    recs = benchwatch.serve_records(
-        "serve_sustained_load", _serve_metric_line()["serve"],
-        platform="cpu")
-    by_metric = {r["metric"]: r for r in recs}
-    assert set(by_metric) == {"serve::verifies_per_s", "serve::p50_ms",
-                              "serve::p99_ms"}
-    for rec in recs:
-        assert benchwatch.validate_record(rec) == []
-        assert rec["source"] == "serve"
-        assert rec["via_metric"] == "serve_sustained_load"
-    v = by_metric["serve::verifies_per_s"]
-    assert v["value"] == 123.4 and v["unit"] == "verifies/s"
-    assert v["serve"]["steady"] is True
-    assert v["serve"]["queue_depth"]["hist"]
-    assert by_metric["serve::p99_ms"]["value"] == 25.0
-
-
-def test_serve_records_malformed_block_yields_nothing():
-    assert benchwatch.serve_records("m", None) == []
-    assert benchwatch.serve_records("m", {"steady": True}) == []
-    assert benchwatch.serve_records("m", "fast") == []
-
-
-def test_emission_lands_serve_records_in_history(tmp_path, monkeypatch):
-    hist = tmp_path / "hist.jsonl"
-    monkeypatch.setenv("CST_BENCHWATCH_HISTORY", str(hist))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    n = benchwatch.append_emission(_serve_metric_line(), ts=time.time())
-    assert n == 4       # bench_emit line + 3 serve:: records
-    records, skipped, warns = benchwatch.load_history(hist)
-    assert not skipped and not warns
-    by_metric = {r["metric"]: r for r in records}
-    assert by_metric["serve_sustained_load"]["source"] == "bench_emit"
-    assert by_metric["serve::verifies_per_s"]["source"] == "serve"
-    assert all(r["platform"] == "cpu" for r in records)
-
-
-def test_bench_round_tail_mines_serve_records(tmp_path):
-    wrapper = {"n": 77, "rc": 0,
-               "tail": json.dumps(_serve_metric_line()) + "\n"}
-    path = tmp_path / "BENCH_r77.json"
-    path.write_text(json.dumps(wrapper))
-    records, warnings = benchwatch.parse_bench_round(path)
-    assert not warnings
-    by_metric = {r["metric"]: r for r in records}
-    srec = by_metric["serve::verifies_per_s"]
-    assert srec["source"] == "serve" and srec["round"] == 77
-    assert srec["serve"]["windows"] == [120.0, 125.0, 124.0]
-
-
-def test_report_thresholds_gate_serve_metrics():
-    from consensus_specs_tpu.telemetry.report import evaluate_thresholds
-
-    def rows(records):
-        return {r["id"]: r for r in evaluate_thresholds(records)}
-
-    tpu_good = [
-        benchwatch.make_record("serve", "serve::verifies_per_s", 50_000.0,
-                               unit="verifies/s", platform="tpu", ts=1.0),
-        benchwatch.make_record("serve", "serve::p99_ms", 80.0,
-                               unit="ms", platform="tpu", ts=1.0),
-    ]
-    got = rows(tpu_good)
-    assert got["serve-throughput"]["status"] == "PASS"
-    assert got["serve-p99"]["status"] == "PASS"
-
-    tpu_bad = [
-        benchwatch.make_record("serve", "serve::verifies_per_s", 500.0,
-                               unit="verifies/s", platform="tpu", ts=1.0),
-        benchwatch.make_record("serve", "serve::p99_ms", 5000.0,
-                               unit="ms", platform="tpu", ts=1.0),
-    ]
-    got = rows(tpu_bad)
-    assert got["serve-throughput"]["status"] == "FAIL"
-    assert got["serve-p99"]["status"] == "FAIL"
-
-    cpu_only = [
-        benchwatch.make_record("serve", "serve::verifies_per_s", 5.0,
-                               unit="verifies/s", platform="cpu", ts=1.0),
-    ]
-    got = rows(cpu_only)
-    # TPU acceptance criteria: a CPU smoke must read "no data", not FAIL
-    assert got["serve-throughput"]["status"] == "no data"
-    assert got["serve-p99"]["status"] == "no data"
 
 
 # --- telemetry gauges (serve counter tracks) ---------------------------------

@@ -4,7 +4,7 @@ Pins the properties the instrumented hot path relies on: disabled mode
 is a true no-op with a measured overhead bound, spans nest and unwind
 through exceptions, the snapshot schema is stable, the registry is
 thread-safe, the Chrome-trace export is valid trace-event JSON, and the
-bench `"telemetry"` sub-object schema is enforced both ways.
+spans the benchmark's readers count reach a profiler session.
 """
 
 from __future__ import annotations
@@ -24,9 +24,7 @@ def _clean_registry():
     """Each test starts disabled with an empty registry and an empty
     span-nesting stack, and restores EXACTLY what it found: on
     CST_TELEMETRY=1 runs the process registry is accumulating
-    session-wide data (per-test spans, deferred-batch counters) that the
-    end-of-session snapshot must keep, and the conftest per-test wrapper
-    span sits on the nesting stack."""
+    session-wide data (deferred-batch counters) that must survive."""
     saved = core._save_state()
     was_enabled = telemetry.enabled()
     stack = core._span_stack()
@@ -262,62 +260,7 @@ def test_jsonl_export(tmp_path):
     assert [e["name"] for e in lines] == ["a", "b"]
 
 
-# --- bench block contract ---------------------------------------------------
-
-
-def test_bench_block_schema_valid():
-    telemetry.configure(enabled=True)
-    telemetry.count("bls.lanes.live", 10)
-    telemetry.count("bls.lanes.padded", 32)
-    telemetry.count("msm.route.host", 3)
-    telemetry.observe("kernel.compile_first_s", 1.5)
-    telemetry.observe("kernel.run_s", 0.1)
-    telemetry.set_meta("compile_cache.dir", "/x")
-    block = telemetry.bench_block()
-    assert telemetry.validate_bench_block(block) == []
-    assert block["compile_s"] == 1.5
-    assert block["run_s"] == 0.1
-    assert block["padding"]["waste_frac"] == round(1 - 10 / 32, 4)
-    assert block["routing"]["msm_host"] == 3
-    assert block["meta"] == {"compile_cache.dir": "/x"}
-
-
-def test_embed_bench_block_protocol():
-    telemetry.configure(enabled=True)
-    telemetry.count("bls.lanes.live", 1)
-    rec = telemetry.embed_bench_block({"metric": "m"})
-    assert telemetry.validate_bench_block(rec["telemetry"]) == []
-    # aggregates were reset for the next config
-    assert telemetry.snapshot()["counters"] == {}
-    # disabled: pass-through untouched
-    telemetry.configure(enabled=False)
-    assert telemetry.embed_bench_block({"metric": "m"}) == {"metric": "m"}
-
-
-def test_bench_block_explicit_split():
-    telemetry.configure(enabled=True)
-    block = telemetry.bench_block(compile_s=81.0, run_s=0.31)
-    assert telemetry.validate_bench_block(block) == []
-    assert block["compile_s"] == 81.0 and block["run_s"] == 0.31
-
-
-def test_validate_bench_block_rejects_malformed():
-    assert telemetry.validate_bench_block(None)
-    assert telemetry.validate_bench_block({})
-    good = telemetry.bench_block(compile_s=1.0, run_s=1.0)
-    for breakage in (
-        lambda b: b.pop("padding"),
-        lambda b: b["routing"].pop("msm_host"),
-        lambda b: b.__setitem__("compile_s", "fast"),
-        lambda b: b["padding"].__setitem__("waste_frac", 2.0),
-        lambda b: b["routing"].__setitem__("h2c_device", -1),
-    ):
-        broken = json.loads(json.dumps(good))
-        breakage(broken)
-        assert telemetry.validate_bench_block(broken), breakage
-
-
-# --- add_event / span_seconds (benchwatch phase attribution) ----------------
+# --- add_event / span_seconds ----------------------------------------------
 
 
 def test_add_event_aggregates_like_a_span():
@@ -355,11 +298,14 @@ def test_span_seconds_point_read():
 # --- the profiler half ------------------------------------------------------
 
 
-def _wire_statement():
+def _wire_statement(k: int = 0):
+    """A statement of its own for each `k`: the suite memoizes the parse
+    of wire bytes it has seen, which would then open no parse phase."""
     from consensus_specs_tpu.ops.bls import ciphersuite
 
-    msg = b"\x07" * 32
-    return ciphersuite.SkToPk(5), msg, ciphersuite.Sign(5, msg)
+    sk = 9_100 + k
+    msg = bytes([0xC0 + k]) * 32
+    return ciphersuite.SkToPk(sk), msg, ciphersuite.Sign(sk, msg)
 
 
 def _cst_host_events(logdir) -> list:
@@ -380,35 +326,62 @@ def _cst_host_events(logdir) -> list:
     return out
 
 
-def test_profiler_captures_parse_phases_with_registry_off(tmp_path):
+PARSE_SPANS = ("serve.parse", "bls.decompress_g1", "bls.subgroup_g1",
+               "bls.decompress_g2", "bls.subgroup_g2")
+
+
+@pytest.mark.parametrize("name", PARSE_SPANS)
+def test_profiler_captures_parse_phases_with_registry_off(tmp_path, name):
     """A profiler session sees the wire parse and its four phases, nested,
     on the profiler's clock, with the registry off; the program's own
-    count of those spans agrees with the trace."""
+    count of each span agrees with the trace.  `parse_ms` and
+    `subgroup_check_ms` read these counts: each name is a case."""
     import jax
 
     from consensus_specs_tpu.serve import ServeExecutor
 
-    pk, msg, sig = _wire_statement()
+    pk, msg, sig = _wire_statement(PARSE_SPANS.index(name))
     ex = ServeExecutor()
     with jax.profiler.trace(str(tmp_path)):
         ex.submit_fast_aggregate_verify([pk], msg, sig)
     events = _cst_host_events(tmp_path)
     (parse,) = [e for e in events if e[0] == "cst.serve.parse"]
-    phases = {e[0]: e for e in events if e[0].startswith("cst.bls.")}
-    assert set(phases) == {"cst.bls.decompress_g1", "cst.bls.subgroup_g1",
-                           "cst.bls.decompress_g2", "cst.bls.subgroup_g2"}
-    for name, line, start, end in phases.values():
-        assert line == parse[1], name
-        assert parse[2] <= start <= end <= parse[3], name
+    assert {e[0] for e in events} == {"cst." + n for n in PARSE_SPANS}
+    (event,) = [e for e in events if e[0] == "cst." + name]
+    assert event[1] == parse[1]
+    assert parse[2] <= event[2] <= event[3] <= parse[3]
     counted = telemetry.profiled_spans()
     assert {k: v["count"] for k, v in counted.items()} == {
-        "serve.parse": 1, "bls.decompress_g1": 1, "bls.subgroup_g1": 1,
-        "bls.decompress_g2": 1, "bls.subgroup_g2": 1}
-    for name, line, start, end in events:
-        total_s = counted[name[len("cst."):]]["total_s"]
-        assert total_s == pytest.approx((end - start) / 1e9, abs=1e-4)
+        n: 1 for n in PARSE_SPANS}
+    assert counted[name]["total_s"] == pytest.approx(
+        (event[3] - event[2]) / 1e9, abs=1e-4)
     # the registry half stayed off
     assert telemetry.snapshot()["spans"] == {}
+
+
+@pytest.mark.parametrize("name", ["bls.prepare", "bls.enqueue"])
+def test_profiler_counts_the_batch_prep_spans(tmp_path, monkeypatch, name):
+    """`batch_prep_ms` reads `bls.prepare` and `bls.enqueue`: one served
+    verify batch, its host preparation and enqueue real and its kernel
+    call stubbed, counts each once inside a profiler session."""
+    import jax
+    import jax.numpy as jnp
+
+    from consensus_specs_tpu.ops import bls_batch
+    from consensus_specs_tpu.serve import ServeExecutor
+
+    monkeypatch.setattr(bls_batch, "_dispatch",
+                        lambda kernel, fn, args, block=True:
+                        jnp.asarray(True))
+    pk, msg, sig = _wire_statement()
+    ex = ServeExecutor()
+    with jax.profiler.trace(str(tmp_path)):
+        fut = ex.submit_fast_aggregate_verify([pk], msg, sig)
+        ex.drain()
+    assert fut.result() is True
+    assert ex.stats()["batches"] == 1
+    assert telemetry.profiled_spans()[name]["count"] == 1
+    assert "cst." + name in {e[0] for e in _cst_host_events(tmp_path)}
 
 
 def test_no_session_and_registry_off_span_is_the_noop():
